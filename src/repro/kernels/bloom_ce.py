@@ -14,7 +14,9 @@ of the logits row instead of re-running the max/exp-sum reduction — the
   grid = (nT,)
   z    — block (Tt, m) at (t, 0)
   h    — block (Tt, k) at (t, 0)
-  loss/lse — blocks (Tt,) at (t,);  bwd adds g (Tt,) in, dz (Tt, m) out.
+  loss/lse — blocks (Tt, 1) at (t, 0);  bwd adds g (Tt, 1) in, dz (Tt, m)
+         out.  The k-pick is a masked row sum per hash, not a lane gather
+         (Mosaic gathers lanes only within one vector register).
 """
 from __future__ import annotations
 
@@ -24,27 +26,46 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import onehot_count, pad_axis, resolve_interpret
+from repro.kernels.common import (onehot_count, pad_axis, resolve_interpret,
+                                  sublane_rows)
 
 
 # --------------------------------------------------------------------------
 # Forward (loss + lse residual)
 # --------------------------------------------------------------------------
 
+def _picked_sum(z, h):
+    """(Tt, 1) sum_j z[t, h[t, j]] without a lane gather: one masked row
+    sum per hash over an iota compare (exactly one lane matches, so each
+    pick is copied, not rounded)."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, z.shape, 1)
+    total = None
+    for j in range(h.shape[1]):
+        pj = jnp.sum(jnp.where(iota == h[:, j:j + 1], z, 0.0), axis=-1,
+                     keepdims=True)
+        total = pj if total is None else total + pj
+    return total
+
+
 def _fwd_kernel(z_ref, h_ref, loss_ref, lse_ref):
     z = z_ref[...].astype(jnp.float32)             # (Tt, m)
     h = h_ref[...]                                 # (Tt, k)
     zmax = z.max(axis=-1, keepdims=True)
-    lse = jnp.log(jnp.sum(jnp.exp(z - zmax), axis=-1)) + zmax[:, 0]
-    picked = jnp.take_along_axis(z, h, axis=-1)    # (Tt, k)
-    loss_ref[...] = lse - picked.mean(-1)
+    lse = jnp.log(jnp.sum(jnp.exp(z - zmax), axis=-1, keepdims=True)) + zmax
+    loss_ref[...] = lse - _picked_sum(z, h) / h.shape[1]
     lse_ref[...] = lse
+
+
+def _row_tile(t_tile, T, dtype):
+    """Token tile: at least one native sublane tile of the logits dtype
+    (8 rows f32, 16 bf16) unless the whole (padded) T is one block."""
+    return min(max(t_tile, sublane_rows(dtype)), T)
 
 
 def _ce_fwd(logits, h_idx, t_tile, interpret):
     T, m = logits.shape
     k = h_idx.shape[1]
-    t_tile = min(t_tile, T)
+    t_tile = _row_tile(t_tile, T, logits.dtype)
     logits = pad_axis(logits, 0, t_tile)
     h_idx = pad_axis(h_idx, 0, t_tile)
     Tp = logits.shape[0]
@@ -57,16 +78,16 @@ def _ce_fwd(logits, h_idx, t_tile, interpret):
             pl.BlockSpec((t_tile, k), lambda t: (t, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((t_tile,), lambda t: (t,)),
-            pl.BlockSpec((t_tile,), lambda t: (t,)),
+            pl.BlockSpec((t_tile, 1), lambda t: (t, 0)),
+            pl.BlockSpec((t_tile, 1), lambda t: (t, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Tp,), jnp.float32),
-            jax.ShapeDtypeStruct((Tp,), jnp.float32),
+            jax.ShapeDtypeStruct((Tp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((Tp, 1), jnp.float32),
         ],
         interpret=interpret,
     )(logits, h_idx)
-    return loss[:T], lse[:T]
+    return loss[:T, 0], lse[:T, 0]
 
 
 # --------------------------------------------------------------------------
@@ -75,12 +96,9 @@ def _ce_fwd(logits, h_idx, t_tile, interpret):
 
 def _bwd_kernel(z_ref, h_ref, lse_ref, g_ref, dz_ref, *, k):
     z = z_ref[...].astype(jnp.float32)             # (Tt, m)
-    h = h_ref[...]                                 # (Tt, k)
-    lse = lse_ref[...]                             # (Tt,)
-    g = g_ref[...]                                 # (Tt,)
-    p = jnp.exp(z - lse[:, None])                  # softmax via residual
-    w = onehot_count(h, z.shape[1])                # (Tt, m)
-    dz_ref[...] = g[:, None] * (p - w / k)
+    p = jnp.exp(z - lse_ref[...])                  # softmax via residual
+    w = onehot_count(h_ref[...], z.shape[1])       # (Tt, m)
+    dz_ref[...] = g_ref[...] * (p - w / k)         # g, lse (Tt, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("t_tile", "interpret"))
@@ -93,11 +111,11 @@ def bloom_ce_bwd_pallas(g: jnp.ndarray, logits: jnp.ndarray,
     interpret = resolve_interpret(interpret)
     T, m = logits.shape
     k = h_idx.shape[1]
-    t_tile = min(t_tile, T)
+    t_tile = _row_tile(t_tile, T, logits.dtype)
     logits = pad_axis(logits, 0, t_tile)
     h_idx = pad_axis(h_idx, 0, t_tile)
-    lse = pad_axis(lse, 0, t_tile)
-    g = pad_axis(g, 0, t_tile)                  # 0-cotangent pad rows -> dz 0
+    lse = pad_axis(lse[:, None], 0, t_tile)
+    g = pad_axis(g[:, None], 0, t_tile)         # 0-cotangent pad rows -> dz 0
     Tp = logits.shape[0]
 
     dz = pl.pallas_call(
@@ -106,8 +124,8 @@ def bloom_ce_bwd_pallas(g: jnp.ndarray, logits: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((t_tile, m), lambda t: (t, 0)),
             pl.BlockSpec((t_tile, k), lambda t: (t, 0)),
-            pl.BlockSpec((t_tile,), lambda t: (t,)),
-            pl.BlockSpec((t_tile,), lambda t: (t,)),
+            pl.BlockSpec((t_tile, 1), lambda t: (t, 0)),
+            pl.BlockSpec((t_tile, 1), lambda t: (t, 0)),
         ],
         out_specs=pl.BlockSpec((t_tile, m), lambda t: (t, 0)),
         out_shape=jax.ShapeDtypeStruct((Tp, m), jnp.float32),

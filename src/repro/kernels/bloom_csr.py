@@ -31,7 +31,8 @@ sweeping*:
      ``(nD, NT)`` with entry tiles innermost; ``tok``/``tile_*`` ride in
      as scalar prefetch.  Each step DMAs EXACTLY the segment's live
      cotangent rows from HBM into VMEM scratch (mirroring the forward's
-     row-DMA layout; pad slots are gated off with ``pl.when``), builds
+     row-DMA layout: each row arrives in its tile-aligned row block and
+     is picked out in VMEM; pad slots are gated off with ``pl.when``), builds
      the (e_tile, m_tile) one-hot of the in-tile m values and accumulates
      ``w.T @ rows`` on the MXU into the output block selected by the
      *data-dependent* index map ``tile_mb[ie]``.  Because tiles arrive
@@ -55,8 +56,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import (BWD_M_TILE, onehot_count, pad_axis,
-                                  resolve_interpret)
+from repro.kernels.common import (BWD_M_TILE, fetch_row_blocks,
+                                  onehot_count, pad_axis, pick_row,
+                                  resolve_interpret, sublane_rows)
 
 # Default entry-tile size of the binned backward: one MXU-friendly
 # contraction depth per grid step, and the unit segments are padded to.
@@ -156,7 +158,8 @@ def bin_csr(idx: jnp.ndarray, m: int, m_tile: int = BWD_M_TILE,
 
 
 def _csr_kernel(tok_ref, tmb_ref, tfirst_ref, tlen_ref, val_ref, g_ref,
-                out_ref, rows, sems, *, e_tile, d_tile, m_tile):
+                out_ref, blk, rows_ref, sems, *, e_tile, d_tile, m_tile,
+                rows):
     ie = pl.program_id(1)
     d0 = pl.program_id(0) * d_tile
     e0 = ie * e_tile
@@ -168,33 +171,24 @@ def _csr_kernel(tok_ref, tmb_ref, tfirst_ref, tlen_ref, val_ref, g_ref,
         out_ref[...] = jnp.zeros_like(out_ref)
 
     # DMA exactly the live cotangent rows of this segment tile (pad slots
-    # are skipped — an empty tile touches no HBM at all)
-    copies = []
-    for s in range(e_tile):
-        c = pltpu.make_async_copy(
-            g_ref.at[pl.ds(tok_ref[e0 + s], 1), pl.ds(d0, d_tile)],
-            rows.at[pl.ds(s, 1), :],
-            sems.at[s],
-        )
-        copies.append(c)
-
-        @pl.when(s < n)
-        def _(c=c):
-            c.start()
+    # are skipped — an empty tile touches no HBM at all); each arrives in
+    # its tile-aligned row block (kernels.common.fetch_row_blocks)
+    offs, copies = fetch_row_blocks(
+        g_ref, [tok_ref[e0 + s] for s in range(e_tile)], blk, sems, d0,
+        d_tile, rows, gate=lambda s: s < n)
     for s, c in enumerate(copies):
-        @pl.when(s < n)
-        def _(c=c):
-            c.wait()
+        pl.when(s < n)(c.wait)
 
     @pl.when(n > 0)
     def _():
+        for s in range(e_tile):
+            rows_ref[s:s + 1, :] = pick_row(blk, s, offs[s])
         base = tmb_ref[ie] * m_tile
         valid = val_ref[...] >= 0                        # (e_tile, 1)
         w = onehot_count(val_ref[...], m_tile, base)     # (e_tile, m_tile)
-        g_rows = rows[...].astype(jnp.float32)           # (e_tile, d_tile)
         # pad slots carry stale scratch; select them to 0 so the matmul
         # can never multiply garbage (0 * NaN would poison the block)
-        g_rows = jnp.where(valid, g_rows, 0.0)
+        g_rows = jnp.where(valid, rows_ref[...], 0.0)    # (e_tile, d_tile)
         out_ref[...] += jnp.dot(w.T, g_rows,
                                 preferred_element_type=jnp.float32)
 
@@ -221,7 +215,8 @@ def csr_scatter_add_pallas(g: jnp.ndarray, bins: CSRBins, m: int,
             f"bins were built for (m={bins.m}, m_tile={bins.m_tile}) but "
             f"the kernel was called with (m={m}, m_tile={m_tile}) — "
             "mismatched bins would scatter into the wrong output blocks")
-    g = pad_axis(g, 1, d_tile)
+    rows = sublane_rows(g.dtype)
+    g = pad_axis(pad_axis(g, 1, d_tile), 0, rows)
     mp = m + ((-m) % m_tile)
     Dp = g.shape[1]
     NT = bins.n_tiles
@@ -229,14 +224,14 @@ def csr_scatter_add_pallas(g: jnp.ndarray, bins: CSRBins, m: int,
 
     out = pl.pallas_call(
         functools.partial(_csr_kernel, e_tile=e_tile, d_tile=d_tile,
-                          m_tile=m_tile),
+                          m_tile=m_tile, rows=rows),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,                # tok, tile_mb/first/len
             grid=grid,
             in_specs=[
                 pl.BlockSpec((e_tile, 1),
                              lambda id_, ie, tok, tmb, tf, tl: (ie, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),   # g stays in HBM
+                pl.BlockSpec(memory_space=pl.ANY),      # g stays in HBM
             ],
             out_specs=pl.BlockSpec(
                 (m_tile, d_tile),
@@ -244,7 +239,8 @@ def csr_scatter_add_pallas(g: jnp.ndarray, bins: CSRBins, m: int,
                 # owns; sorted tiles revisit it in one consecutive run
                 lambda id_, ie, tok, tmb, tf, tl: (tmb[ie], id_)),
             scratch_shapes=[
-                pltpu.VMEM((e_tile, d_tile), g.dtype),
+                pltpu.VMEM((e_tile, rows, d_tile), g.dtype),
+                pltpu.VMEM((e_tile, d_tile), jnp.float32),
                 pltpu.SemaphoreType.DMA((e_tile,)),
             ],
         ),
@@ -296,10 +292,13 @@ def bloom_decode_bwd_csr_pallas(g: jnp.ndarray, H: jnp.ndarray, m: int,
     if bins is None:
         bins = bin_csr(H, m, m_tile=m_tile, e_tile=e_tile)
     B = g.shape[0]
-    out = csr_scatter_add_pallas(g.T, bins, m, m_tile=m_tile,
-                                 d_tile=min(512, B),
-                                 interpret=interpret)          # (m, B)
-    return out.T
+    # batch columns become lanes: the row DMAs and output blocks need a
+    # whole 128-lane width, so a small batch is padded up to one
+    gT = pad_axis(g.T, 1, 128)
+    out = csr_scatter_add_pallas(gT, bins, m, m_tile=m_tile,
+                                 d_tile=min(512, gT.shape[1]),
+                                 interpret=interpret)          # (m, Bp)
+    return out[:, :B].T
 
 
 # --------------------------------------------------------------------------

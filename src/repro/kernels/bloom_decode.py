@@ -12,10 +12,14 @@ into sequential-HBM + random-VMEM — the memory-hierarchy adaptation of
 DESIGN.md §4.
 
   grid = (nB, nV)
-  logp — block (Bt, m)  at (b, 0)  (revisited across the vocab axis; Pallas
-         keeps it resident in VMEM between consecutive grid steps)
-  H    — block (Vt, k)  at (v, 0)
-  out  — block (Bt, Vt) at (b, v)
+  logp — block (Bt, m) of the (nB, Bt, m) row blocks at (b, 0, 0)
+         (revisited across the vocab axis; Pallas keeps it resident in
+         VMEM between consecutive grid steps)
+  H^T  — block (k, Vt) at (0, v)
+  out  — block (Bt, Vt) at (b, 0, v)
+
+The k-gather is the two-level lane gather shared with the fused top-k
+kernel (bloom_decode_topk.gather_scores).
 
 The DENSE backward inverts the stream: grid (nM, nV) with the vocab axis
 innermost; each step builds the (v_tile, m_tile) one-hot count matrix
@@ -35,8 +39,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import quant
+from repro.kernels.bloom_decode_topk import (LANES, chunk_indices,
+                                             gather_scores, load_resident)
 from repro.kernels.common import (BWD_M_TILE, onehot_count, pad_axis,
                                   resolve_bwd_impl, resolve_interpret)
 
@@ -45,58 +52,57 @@ from repro.kernels.common import (BWD_M_TILE, onehot_count, pad_axis,
 # Forward
 # --------------------------------------------------------------------------
 
-def _fwd_kernel(logp_ref, h_ref, out_ref):
-    logp = logp_ref[...].astype(jnp.float32)       # (Bt, m)
-    h = h_ref[...]                                 # (Vt, k)
-    k = h.shape[1]
-    acc = jnp.take(logp, h[:, 0], axis=1)          # (Bt, Vt)
-    for j in range(1, k):
-        acc = acc + jnp.take(logp, h[:, j], axis=1)
-    out_ref[...] = acc.astype(out_ref.dtype)
+def _fwd_kernel(*refs, k, has_scales):
+    logp_ref = refs[0]
+    s_ref = refs[1] if has_scales else None
+    h_ref, out_ref, lp_ref = refs[1 + has_scales:]
 
+    # int8 logp (DESIGN.md §13) dequantizes once per row block, on the
+    # resident f32 scratch the gather reads
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        load_resident(logp_ref, s_ref, lp_ref)
 
-def _fwd_kernel_scaled(logp_ref, s_ref, h_ref, out_ref):
-    """int8-logp variant (DESIGN.md §13): every gathered element of a
-    batch row shares that row's scale, so the k-gather accumulates in the
-    integer domain's f32 image and dequantizes ONCE on the (Bt, Vt)
-    output tile — one multiply per output, not per gather."""
-    logp = logp_ref[...].astype(jnp.float32)       # (Bt, m) int8 -> f32
-    h = h_ref[...]                                 # (Vt, k)
-    k = h.shape[1]
-    acc = jnp.take(logp, h[:, 0], axis=1)          # (Bt, Vt)
-    for j in range(1, k):
-        acc = acc + jnp.take(logp, h[:, j], axis=1)
-    out_ref[...] = (acc * s_ref[...]).astype(out_ref.dtype)   # s (Bt, 1)
+    def chunk(c, carry):
+        off = pl.multiple_of(c * LANES, LANES)
+        out_ref[:, pl.ds(off, LANES)] = gather_scores(
+            lp_ref, chunk_indices(h_ref, None, c, None, k))
+        return carry
+
+    jax.lax.fori_loop(0, out_ref.shape[1] // LANES, chunk, 0)
 
 
 def _decode_fwd(logp, H, b_tile, v_tile, interpret, scales=None):
     B, m = logp.shape
     d, k = H.shape
-    logp = pad_axis(logp, 0, b_tile)
-    H = pad_axis(H, 0, v_tile)
-    Bp, dp = logp.shape[0], H.shape[0]
+    v_tile += (-v_tile) % LANES
+    logp = pad_axis(pad_axis(logp, 0, b_tile), 1, LANES)
+    Bp, mp = logp.shape
+    nB = Bp // b_tile
+    HT = pad_axis(H, 0, v_tile).T              # (k, dp): ids on lanes
+    dp = HT.shape[1]
 
-    in_specs = [
-        pl.BlockSpec((b_tile, m), lambda b, v: (b, 0)),
-        pl.BlockSpec((v_tile, k), lambda b, v: (v, 0)),
-    ]
-    operands = (logp, H)
-    kernel = _fwd_kernel
+    in_specs = [pl.BlockSpec((None, b_tile, mp), lambda b, v: (b, 0, 0))]
+    operands = [logp.reshape(nB, b_tile, mp)]
     if scales is not None:
-        sg = pad_axis(scales.astype(jnp.float32)[:, None], 0, b_tile)
-        in_specs.insert(1, pl.BlockSpec((b_tile, 1), lambda b, v: (b, 0)))
-        operands = (logp, sg, H)
-        kernel = _fwd_kernel_scaled
+        sg = pad_axis(scales.astype(jnp.float32), 0, b_tile)
+        in_specs.append(pl.BlockSpec((None, b_tile, 1),
+                                     lambda b, v: (b, 0, 0)))
+        operands.append(sg.reshape(nB, b_tile, 1))
+    in_specs.append(pl.BlockSpec((k, v_tile), lambda b, v: (0, v)))
+    operands.append(HT)
 
     out = pl.pallas_call(
-        kernel,
-        grid=(Bp // b_tile, dp // v_tile),
+        functools.partial(_fwd_kernel, k=k, has_scales=scales is not None),
+        grid=(nB, dp // v_tile),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((b_tile, v_tile), lambda b, v: (b, v)),
-        out_shape=jax.ShapeDtypeStruct((Bp, dp), jnp.float32),
+        out_specs=pl.BlockSpec((None, b_tile, v_tile),
+                               lambda b, v: (b, 0, v)),
+        out_shape=jax.ShapeDtypeStruct((nB, b_tile, dp), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((b_tile, mp), jnp.float32)],
         interpret=interpret,
     )(*operands)
-    return out[:B, :d]
+    return out.reshape(Bp, dp)[:B, :d]
 
 
 def _decode_fwd_quant(logp, H, b_tile, v_tile, interpret, table_dtype):

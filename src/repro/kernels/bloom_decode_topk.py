@@ -4,25 +4,29 @@
 The unfused serving decode writes the full (B, d) recovered-score matrix to
 HBM and reads it back for jax.lax.top_k — 2 * B * d * 4 bytes that dominate
 decode cost at LLM vocab scale (qwen3-4b: d = 151 936).  This kernel never
-materializes the score matrix: it streams (v_tile, k) hash-matrix tiles
-through the grid, recovers each (Bt, Vt) score tile in VMEM from the
-resident (Bt, m) log-prob row, and folds it into a running per-batch top-k
-held in VMEM scratch.  HBM traffic drops to
+materializes the score matrix: it streams (k, v_tile) tiles of the
+transposed hash matrix through the grid, recovers each (Bt, Vt) score tile
+in VMEM from the resident (Bt, m) log-prob block, and folds it into a
+running per-batch top-k held in VMEM scratch.  HBM traffic drops to
 
     B*m*4 (logp) + d*k*4 (H) + B*topk*8 (out)        [>= 3.8x fewer bytes
                                                       than decode-then-topk
                                                       at qwen3-4b shapes]
 
   grid = (nB, nV)          — vocab axis innermost
-  logp — block (Bt, m)  at (b, 0)   (VMEM-resident across the vocab sweep)
-  H    — block (Vt, k)  at (v, 0)
-  outs — values (Bt, topk) f32 and ids (Bt, topk) i32 at (b, 0), written
-         once at the last vocab step
-  scratch — running (Bt, topk) best values/ids, reset at v == 0
+  logp — block (Bt, m) of the (nB, Bt, m) row blocks at (b, 0, 0)
+         (VMEM-resident across the vocab sweep; widened to f32 scratch)
+  H^T  — block (k, Vt)  at (0, v)  (vocab ids on lanes)
+  outs — values / ids (Bt, topk) at (b, 0, 0), written once at the last
+         vocab step
+  scratch — running best values/ids (Bt, 128), reset at v == 0
 
-The merge concatenates the running best with the fresh score tile and takes
-``jax.lax.top_k`` over topk + Vt lanes; each vocab id enters the stream
-exactly once, so no dedup pass is needed.
+Mosaic gathers lanes only inside one (8, 128) vector register and has no
+in-kernel ``top_k``, so the score tile is built 128 ids at a time by a
+two-level gather (gather_scores) and merged by an iterative max-extract
+that breaks ties toward the lowest id, exactly as ``jax.lax.top_k`` on the
+materialized scores; each vocab id enters the stream exactly once, so no
+dedup pass is needed.
 
 **Row-skipping grid (serving slot pools, DESIGN.md §8).**  A continuous-
 batching pool at partial occupancy decodes dead slot rows; the dense grid
@@ -42,9 +46,9 @@ least one live slot — bytes scale with occupancy instead of pool size
 bytes at <=50% occupancy).
 
 **Quantized logp + in-kernel hashing (DESIGN.md §13).**  ``table_dtype``
-stores the resident (Bt, m) block in bf16/int8/fp8 — the VMEM gather runs
-on the narrow tile and int8 dequantizes with ONE per-batch-row scale
-multiply on the score tile.  That alone cannot beat the fp32 row by the
+stores the resident (Bt, m) block in bf16/int8/fp8 in HBM; it is widened
+to f32 once per row block in VMEM, int8 with ONE per-batch-row scale
+multiply.  That alone cannot beat the fp32 row by the
 gated 3x: at serving batch sizes the ``d*k*4`` H stream dominates (2.4 MB
 vs 0.24 MB of logp at qwen3-4b/B=8).  So the quantized path also drops H
 entirely: ``hash_spec=(d, k, seed)`` re-derives every vocab tile's hash
@@ -101,99 +105,170 @@ def modeled_hbm_bytes(active, b_tile: int, *, m: int, d: int, k: int,
     return int(n_visited * per_block + B * topk * 8)
 
 
-def _tile_scores(logp, h_ref, iv, v_tile, hash_spec):
-    """(Bt, Vt) raw score tile: k-gather from the resident logp block,
-    indices either streamed from H or re-derived in-kernel."""
+# Lane width of a TPU vector register: the in-kernel gather works on
+# (rows, 128) vregs, so the m axis of the resident block and every vocab
+# tile are padded to multiples of it.
+LANES = 128
+LANE_SHIFT = 7                                  # log2(LANES)
+
+# Sentinel id of the running best's unused lanes: larger than every vocab
+# id, so it loses every lowest-id tie-break and never leaks out.
+_NO_ID = np.iinfo(np.int32).max
+
+
+def chunk_indices(h_ref, base, c, hash_spec, k):
+    """k (1, LANES) int32 hash-index rows of the LANES vocab ids starting
+    at global id ``base`` — lane chunk ``c`` of the current vocab tile.
+
+    Explicit-H path: ``h_ref`` is the (k, v_tile) block of the transposed
+    hash matrix (ids on lanes).  ``hash_spec=(m, k, c1, c2)`` instead
+    re-derives the indices from the id iota via enhanced double hashing —
+    the exact arithmetic of core.hashing.double_hash, with the two mixed
+    salts baked in as static scalars (hashing.double_hash_salts)."""
     if hash_spec is None:
-        h = h_ref[...]                              # (Vt, k)
-        k = h.shape[1]
-        scores = jnp.take(logp, h[:, 0], axis=1)    # (Bt, Vt)
-        for j in range(1, k):
-            scores = scores + jnp.take(logp, h[:, j], axis=1)
-        return scores
-    # Enhanced double hashing on the tile's id iota — the exact
-    # arithmetic of core.hashing.double_hash, with the two mixed salts
-    # baked in as static scalars (hashing.double_hash_salts).
+        off = pl.multiple_of(c * LANES, LANES)
+        return [h_ref[j:j + 1, pl.ds(off, LANES)] for j in range(k)]
     m, k, c1, c2 = hash_spec
-    vid = (jax.lax.broadcasted_iota(jnp.int32, (1, v_tile), 1)
-           + iv * v_tile).astype(jnp.uint32)
+    vid = (jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+           + base).astype(jnp.uint32)
     h1 = hashing.splitmix32(vid ^ np.uint32(c1)) % np.uint32(m)
     h2 = hashing.splitmix32(vid ^ np.uint32(c2)) \
         % np.uint32(max(m - 1, 1)) + np.uint32(1)
-    scores = None
+    out = []
     for j in range(k):
         tri = (j ** 3 - j) // 6 % m
         hj = (h1 + np.uint32(j) * h2 + np.uint32(tri)) % np.uint32(m)
-        hj = hj.astype(jnp.int32).reshape(v_tile)
-        sj = jnp.take(logp, hj, axis=1)
-        scores = sj if scores is None else scores + sj
+        out.append(hj.astype(jnp.int32))
+    return out
+
+
+def _lane_gather(x, idx):
+    """out[b, l] = x[b, idx[b, l]] within one (rows, LANES) register: the
+    gather form Mosaic lowers (tpu.dynamic_gather along lanes).  Spelled
+    as lax.gather because jnp.take_along_axis drops size-1 row axes into
+    a form the lowering refuses."""
+    dn = jax.lax.GatherDimensionNumbers(
+        offset_dims=(), collapsed_slice_dims=(1,), start_index_map=(1,),
+        operand_batching_dims=(0,), start_indices_batching_dims=(0,))
+    return jax.lax.gather(x, idx[..., None], dn, slice_sizes=(1, 1),
+                          mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
+def gather_scores(lp_ref, idxs):
+    """(Bt, LANES) Eq. 3 scores sum_j lp[b, idxs[j]] for one lane chunk.
+
+    ``lp_ref`` is the resident (Bt, mp) f32 log-prob block, mp a multiple
+    of LANES.  Mosaic gathers lanes only within one vector register, so
+    the k-gather is two-level: sweep the mp / LANES source registers and,
+    for each, gather every index's lane (a lane gather on one vreg)
+    and keep it where the index's register matches.  Exactly one register
+    matches per index, so each gathered value is copied, never combined,
+    and the k terms are summed in j order as in the XLA oracle."""
+    bt, mp = lp_ref.shape
+    rows = [jnp.broadcast_to(i >> LANE_SHIFT, (bt, LANES)) for i in idxs]
+    lanes = [jnp.broadcast_to(i & (LANES - 1), (bt, LANES)) for i in idxs]
+
+    def body(r, accs):
+        src = lp_ref[:, pl.ds(pl.multiple_of(r * LANES, LANES), LANES)]
+        return tuple(
+            jnp.where(rows[j] == r, _lane_gather(src, lanes[j]), accs[j])
+            for j in range(len(idxs)))
+
+    zero = jnp.zeros((bt, LANES), jnp.float32)
+    accs = jax.lax.fori_loop(0, mp // LANES, body, (zero,) * len(idxs))
+    scores = accs[0]
+    for a in accs[1:]:
+        scores = scores + a
     return scores
 
 
-def _fold_tile(logp_ref, h_ref, s_ref, vals_ref, ids_ref, best_v, best_i, *,
-               iv, topk, v_tile, d, hash_spec):
+def load_resident(logp_ref, s_ref, lp_ref):
+    """Widen the (Bt, mp) logp block into the f32 gather scratch.  int8
+    dequantizes HERE with one per-row scale multiply, before the gather,
+    so the gathered values (and tie patterns) are bit-identical to the
+    XLA dequantize-then-decode oracle."""
+    x = logp_ref[...].astype(jnp.float32)
+    if s_ref is not None:
+        x = x * s_ref[...]                          # s (Bt, 1)
+    lp_ref[...] = x
+
+
+def _merge_topk(best_v, best_i, sc_ref, iv, v_tile, topk):
+    """Fold the (Bt, v_tile) score tile into the running best.
+
+    Iterative max-extract over [best, tile]: each round takes the row max
+    and, among the entries equal to it, the LOWEST id — the tie order of
+    jax.lax.top_k on the materialized score vector.  Unused lanes of the
+    best hold (-inf, _NO_ID); every real id is lower, so a sentinel never
+    wins while a real candidate remains (every tile of ids ascends, and
+    the first tile alone holds >= topk real ids)."""
+    bt = sc_ref.shape[0]
+    gid = jax.lax.broadcasted_iota(jnp.int32, (bt, v_tile), 1) + iv * v_tile
+    cand_v = jnp.concatenate([best_v[...], sc_ref[...]], axis=1)
+    cand_i = jnp.concatenate([best_i[...], gid], axis=1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bt, LANES), 1)
+    new_v = jnp.full((bt, LANES), -jnp.inf, jnp.float32)
+    new_i = jnp.full((bt, LANES), _NO_ID, jnp.int32)
+    alive = jnp.ones(cand_v.shape, jnp.bool_)
+    for t in range(topk):
+        masked = jnp.where(alive, cand_v, -jnp.inf)
+        mx = jnp.max(masked, axis=1, keepdims=True)
+        sel = jnp.min(jnp.where(alive & (masked == mx), cand_i, _NO_ID),
+                      axis=1, keepdims=True)
+        new_v = jnp.where(lane == t, mx, new_v)
+        new_i = jnp.where(lane == t, sel, new_i)
+        alive = alive & (cand_i != sel)
+    best_v[...] = new_v
+    best_i[...] = new_i
+
+
+def _fold_tile(logp_ref, s_ref, h_ref, vals_ref, ids_ref, lp_ref, sc_ref,
+               best_v, best_i, *, iv, topk, v_tile, d, k, hash_spec):
     """One (row-block, vocab-tile) fold of the streaming top-k — shared
     by the dense and the row-skipping grids."""
-    logp = logp_ref[...].astype(jnp.float32)        # (Bt, m)
-    if s_ref is not None:
-        # int8 dequant happens HERE, on the VMEM-resident (Bt, m) block:
-        # one per-batch-row scale multiply before the k-gather, so the
-        # gathered f32 values (and thus tie patterns) are bit-identical
-        # to the XLA dequantize-then-decode oracle.
-        logp = logp * s_ref[...]                    # s (Bt, 1)
-    scores = _tile_scores(logp, h_ref, iv, v_tile, hash_spec)
-
-    b_tile = scores.shape[0]
-    gid = jax.lax.broadcasted_iota(jnp.int32, (b_tile, v_tile), 1) \
-        + iv * v_tile
-    scores = jnp.where(gid < d, scores, -jnp.inf)   # mask vocab padding
-
-    # Seed the running best from the first tile (requires topk <= v_tile)
-    # rather than -inf/-1 sentinels: with fully -inf rows (masked vocabs)
-    # a sentinel would win the top_k tie-break and leak id -1.  Seeding
-    # also reproduces jax.lax.top_k's lowest-index tie ordering exactly —
-    # best entries (earlier vocab ids) sit first in the concat, and
-    # -inf-masked pad ids can never displace them.
     @pl.when(iv == 0)
     def _():
-        top_v, sel = jax.lax.top_k(scores, topk)
-        best_v[...] = top_v
-        best_i[...] = jnp.take_along_axis(gid, sel, axis=-1)
+        load_resident(logp_ref, s_ref, lp_ref)
+        best_v[...] = jnp.full(best_v.shape, -jnp.inf, jnp.float32)
+        best_i[...] = jnp.full(best_i.shape, _NO_ID, jnp.int32)
 
-    @pl.when(iv > 0)
-    def _():
-        cat_v = jnp.concatenate([best_v[...], scores], axis=-1)
-        cat_i = jnp.concatenate([best_i[...], gid], axis=-1)
-        top_v, sel = jax.lax.top_k(cat_v, topk)
-        best_v[...] = top_v
-        best_i[...] = jnp.take_along_axis(cat_i, sel, axis=-1)
+    bt = sc_ref.shape[0]
+
+    def chunk(c, carry):
+        base = iv * v_tile + c * LANES
+        scores = gather_scores(lp_ref,
+                               chunk_indices(h_ref, base, c, hash_spec, k))
+        gid = jax.lax.broadcasted_iota(jnp.int32, (bt, LANES), 1) + base
+        sc_ref[:, pl.ds(pl.multiple_of(c * LANES, LANES), LANES)] = \
+            jnp.where(gid < d, scores, -jnp.inf)    # mask vocab padding
+        return carry
+
+    jax.lax.fori_loop(0, v_tile // LANES, chunk, 0)
+    _merge_topk(best_v, best_i, sc_ref, iv, v_tile, topk)
 
     @pl.when(iv == pl.num_programs(1) - 1)
     def _():
-        vals_ref[...] = best_v[...]
-        ids_ref[...] = best_i[...]
+        vals_ref[...] = best_v[:, :topk]
+        ids_ref[...] = best_i[:, :topk]
 
 
 def _split_refs(refs, has_scales, hash_spec):
-    """(logp[, s][, h], vals, ids, best_v, best_i) positional unpack for
-    the dense/skip kernels' variable operand lists."""
+    """(logp[, s][, h], vals, ids, lp, sc, best_v, best_i) positional
+    unpack for the dense/skip kernels' variable operand lists."""
     refs = list(refs)
     logp_ref = refs.pop(0)
     s_ref = refs.pop(0) if has_scales else None
     h_ref = refs.pop(0) if hash_spec is None else None
-    vals_ref, ids_ref, best_v, best_i = refs
-    return logp_ref, s_ref, h_ref, vals_ref, ids_ref, best_v, best_i
+    return (logp_ref, s_ref, h_ref, *refs)
 
 
-def _kernel(*refs, topk, v_tile, d, has_scales, hash_spec):
-    logp_ref, s_ref, h_ref, vals_ref, ids_ref, best_v, best_i = \
-        _split_refs(refs, has_scales, hash_spec)
-    _fold_tile(logp_ref, h_ref, s_ref, vals_ref, ids_ref, best_v, best_i,
-               iv=pl.program_id(1), topk=topk, v_tile=v_tile, d=d,
+def _kernel(*refs, topk, v_tile, d, k, has_scales, hash_spec):
+    _fold_tile(*_split_refs(refs, has_scales, hash_spec),
+               iv=pl.program_id(1), topk=topk, v_tile=v_tile, d=d, k=k,
                hash_spec=hash_spec)
 
 
-def _kernel_skip(occ_ref, pin_ref, *refs, topk, v_tile, d, has_scales,
+def _kernel_skip(occ_ref, pin_ref, *refs, topk, v_tile, d, k, has_scales,
                  hash_spec):
     """Row-skipping variant: ``occ_ref``/``pin_ref`` are the scalar-
     prefetched per-block occupancy / logp-block pin arrays (also consumed
@@ -201,16 +276,15 @@ def _kernel_skip(occ_ref, pin_ref, *refs, topk, v_tile, d, has_scales,
     their logp/H block indices revisit resident blocks (no copy), the fold
     is skipped, and the output block — which IS flushed for every b — is
     written as (-inf, 0), matching recover_topk's dead-row masking."""
-    logp_ref, s_ref, h_ref, vals_ref, ids_ref, best_v, best_i = \
-        _split_refs(refs, has_scales, hash_spec)
+    split = _split_refs(refs, has_scales, hash_spec)
+    vals_ref, ids_ref = split[3], split[4]
     ib = pl.program_id(0)
     iv = pl.program_id(1)
     act = occ_ref[ib] > 0
 
     @pl.when(act)
     def _():
-        _fold_tile(logp_ref, h_ref, s_ref, vals_ref, ids_ref, best_v,
-                   best_i, iv=iv, topk=topk, v_tile=v_tile, d=d,
+        _fold_tile(*split, iv=iv, topk=topk, v_tile=v_tile, d=d, k=k,
                    hash_spec=hash_spec)
 
     @pl.when(jnp.logical_not(act) & (iv == pl.num_programs(1) - 1))
@@ -282,60 +356,73 @@ def bloom_decode_topk_pallas(logp: jnp.ndarray, H: jnp.ndarray | None,
     else:
         d, k = H.shape
         kern_hash = None
-    if not (0 < topk <= d):
-        raise ValueError(f"need 0 < topk <= d, got topk={topk} d={d}")
+    if not (0 < topk <= min(d, LANES)):
+        raise ValueError(f"need 0 < topk <= min(d, {LANES}), got "
+                         f"topk={topk} d={d}")
     b_tile = min(b_tile, B)
-    v_tile = max(min(v_tile, d), topk)   # first tile seeds the running best
+    # the first tile seeds the running best with >= topk real ids; tiles
+    # are whole lane chunks
+    v_tile = max(min(v_tile, d), topk)
+    v_tile += (-v_tile) % LANES
 
     table_dtype = quant.resolve_table_dtype(table_dtype)
     scales = None
     if table_dtype is not None:
         logp, scales = quant.quantize_table(logp, table_dtype)
 
-    logp = pad_axis(logp, 0, b_tile)
-    Bp = logp.shape[0]
+    # (nB, b_tile, mp) blocks: each row block is a whole trailing (b_tile,
+    # mp) slab, which every dtype's tiling accepts at any b_tile
+    logp = pad_axis(pad_axis(logp, 0, b_tile), 1, LANES)
+    Bp, mp = logp.shape
+    nB = Bp // b_tile
+    logp = logp.reshape(nB, b_tile, mp)
+    dp = d + ((-d) % v_tile)                   # padded ids masked via d
     if H is not None:
-        H = pad_axis(H, 0, v_tile)             # padded ids masked via d
-        dp = H.shape[0]
-    else:
-        dp = d + ((-d) % v_tile)               # iota ids masked via d
-    grid = (Bp // b_tile, dp // v_tile)
+        H = pad_axis(H, 0, v_tile).T           # (k, dp): ids on lanes
+    grid = (nB, dp // v_tile)
     has_scales = scales is not None
+    if has_scales:
+        scales = pad_axis(scales.astype(jnp.float32), 0, b_tile)
+        scales = scales.reshape(nB, b_tile, 1)
 
     out_shape = [
-        jax.ShapeDtypeStruct((Bp, topk), jnp.float32),
-        jax.ShapeDtypeStruct((Bp, topk), jnp.int32),
+        jax.ShapeDtypeStruct((nB, b_tile, topk), jnp.float32),
+        jax.ShapeDtypeStruct((nB, b_tile, topk), jnp.int32),
     ]
     scratch_shapes = [
-        pltpu.VMEM((b_tile, topk), jnp.float32),
-        pltpu.VMEM((b_tile, topk), jnp.int32),
+        pltpu.VMEM((b_tile, mp), jnp.float32),      # widened logp block
+        pltpu.VMEM((b_tile, v_tile), jnp.float32),  # score tile
+        pltpu.VMEM((b_tile, LANES), jnp.float32),   # running best values
+        pltpu.VMEM((b_tile, LANES), jnp.int32),     # running best ids
     ]
-    kwargs = dict(topk=topk, v_tile=v_tile, d=d, has_scales=has_scales,
+    kwargs = dict(topk=topk, v_tile=v_tile, d=d, k=k, has_scales=has_scales,
                   hash_spec=kern_hash)
+    row_blk = (None, b_tile, mp)
+    out_blk = (None, b_tile, topk)
 
     if active is None:
-        in_specs = [pl.BlockSpec((b_tile, m), lambda b, v: (b, 0))]
+        in_specs = [pl.BlockSpec(row_blk, lambda b, v: (b, 0, 0))]
         operands = [logp]
         if has_scales:
-            in_specs.append(pl.BlockSpec((b_tile, 1), lambda b, v: (b, 0)))
-            operands.append(pad_axis(scales.astype(jnp.float32)[:, None],
-                                     0, b_tile))
+            in_specs.append(pl.BlockSpec((None, b_tile, 1),
+                                         lambda b, v: (b, 0, 0)))
+            operands.append(scales)
         if H is not None:
-            in_specs.append(pl.BlockSpec((v_tile, k), lambda b, v: (v, 0)))
+            in_specs.append(pl.BlockSpec((k, v_tile), lambda b, v: (0, v)))
             operands.append(H)
         vals, ids = pl.pallas_call(
             functools.partial(_kernel, **kwargs),
             grid=grid,
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((b_tile, topk), lambda b, v: (b, 0)),
-                pl.BlockSpec((b_tile, topk), lambda b, v: (b, 0)),
+                pl.BlockSpec(out_blk, lambda b, v: (b, 0, 0)),
+                pl.BlockSpec(out_blk, lambda b, v: (b, 0, 0)),
             ],
             out_shape=out_shape,
             scratch_shapes=scratch_shapes,
             interpret=interpret,
         )(*operands)
-        return vals[:B], ids[:B]
+        return vals.reshape(Bp, topk)[:B], ids.reshape(Bp, topk)[:B]
 
     occ, pin = block_occupancy(active, b_tile)
     nv_last = grid[1] - 1
@@ -347,29 +434,27 @@ def bloom_decode_topk_pallas(logp: jnp.ndarray, H: jnp.ndarray | None,
         # instead prefetch tile 0, the tile that first live sweep starts
         # with, so they too fetch nothing the live sweeps would not
         # fetch anyway.
-        pl.BlockSpec((b_tile, m), lambda b, v, occ, pin: (pin[b], 0)),
+        pl.BlockSpec(row_blk, lambda b, v, occ, pin: (pin[b], 0, 0)),
     ]
     operands = [logp]
     if has_scales:
-        in_specs.append(pl.BlockSpec((b_tile, 1),
-                                     lambda b, v, occ, pin: (pin[b], 0)))
-        operands.append(pad_axis(scales.astype(jnp.float32)[:, None],
-                                 0, b_tile))
+        in_specs.append(pl.BlockSpec((None, b_tile, 1),
+                                     lambda b, v, occ, pin: (pin[b], 0, 0)))
+        operands.append(scales)
     if H is not None:
         in_specs.append(pl.BlockSpec(
-            (v_tile, k),
+            (k, v_tile),
             lambda b, v, occ, pin:
-            (jnp.where(occ[b] > 0, v,
-                       jnp.where(pin[b] > b, 0, nv_last)),
-             0)))
+            (0, jnp.where(occ[b] > 0, v,
+                          jnp.where(pin[b] > b, 0, nv_last)))))
         operands.append(H)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((b_tile, topk), lambda b, v, occ, pin: (b, 0)),
-            pl.BlockSpec((b_tile, topk), lambda b, v, occ, pin: (b, 0)),
+            pl.BlockSpec(out_blk, lambda b, v, occ, pin: (b, 0, 0)),
+            pl.BlockSpec(out_blk, lambda b, v, occ, pin: (b, 0, 0)),
         ],
         scratch_shapes=scratch_shapes,
     )
@@ -378,5 +463,5 @@ def bloom_decode_topk_pallas(logp: jnp.ndarray, H: jnp.ndarray | None,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(occ, pin, logp, *operands[1:])
-    return vals[:B], ids[:B]
+    )(occ, pin, *operands)
+    return vals.reshape(Bp, topk)[:B], ids.reshape(Bp, topk)[:B]

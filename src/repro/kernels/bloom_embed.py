@@ -7,8 +7,10 @@ Backward:  dtable[r, :] = sum_{t, j : idx[t, j] == r} g[t, :]   (scatter-add)
 TPU mapping (DESIGN.md §4):
 
 * Forward — token-blocked grid ``(nT, nD)``.  The table is passed ONCE in
-  ``pltpu.ANY`` (it stays in HBM); the kernel issues ``t_tile * k`` async row
-  DMAs per step into a VMEM scratch and reduces over k in-register.  This
+  ``pl.ANY`` (it stays in HBM); the kernel issues ``t_tile * k`` async row
+  DMAs per step into a VMEM scratch and reduces over k in-register.  Each
+  DMA moves the tile-aligned row block that holds the hashed row (a DMA
+  slice must cover whole sublane tiles); the row is picked out in VMEM.  This
   replaces the seed kernel's one-token-per-grid-step layout with
   ``[table] * k`` duplicated operands: operand count drops k+1 -> 2 and grid
   steps drop ``t_tile``x, while the scalar-prefetched index array still lets
@@ -39,108 +41,90 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import quant
-from repro.kernels.common import (BWD_M_TILE, onehot_count, pad_axis,
-                                  resolve_bwd_impl, resolve_interpret)
+from repro.kernels.common import (BWD_M_TILE, fetch_row_blocks,
+                                  onehot_count, pad_axis, pick_row,
+                                  resolve_bwd_impl, resolve_interpret,
+                                  sublane_rows)
 
 
 # --------------------------------------------------------------------------
 # Forward
 # --------------------------------------------------------------------------
 
-def _fwd_kernel(idx_ref, table_ref, out_ref, rows, sems, *, t_tile, k,
-                d_tile):
+def _fwd_kernel(idx_ref, *refs, t_tile, k, d_tile, rows, scaled):
+    """Gather-sum of t_tile tokens x k hash rows for one d-block.
+
+    A DMA may only move whole sublane tiles, so each hashed row arrives
+    inside the ``rows``-row aligned block that holds it (8 rows f32, 16
+    bf16, 32 int8) and is picked out of that block in VMEM.  int8 tables
+    (``scaled``) dequantize with the fetched row's scale, which rides the
+    scalar-prefetch path next to the indices (DESIGN.md §13)."""
+    if scaled:
+        s_ref, table_ref, out_ref, blk, acc, sems = refs
+    else:
+        s_ref = None
+        table_ref, out_ref, blk, acc, sems = refs
     t0 = pl.program_id(0) * t_tile
     d0 = pl.program_id(1) * d_tile
-    copies = []
-    for tt in range(t_tile):
-        for j in range(k):
-            row = idx_ref[t0 + tt, j]
-            c = pltpu.make_async_copy(
-                table_ref.at[pl.ds(row, 1), pl.ds(d0, d_tile)],
-                rows.at[pl.ds(tt * k + j, 1), :],
-                sems.at[tt * k + j],
-            )
-            c.start()
-            copies.append(c)
+    offs, copies = fetch_row_blocks(
+        table_ref, [idx_ref[(t0 + tt) * k + j] for tt in range(t_tile)
+                    for j in range(k)], blk, sems, d0, d_tile, rows)
     for c in copies:
         c.wait()
-    r = rows[...].astype(jnp.float32).reshape(t_tile, k, d_tile)
-    out_ref[...] = r.sum(axis=1).astype(out_ref.dtype)
-
-
-def _fwd_kernel_scaled(idx_ref, s_ref, table_ref, out_ref, rows, sems, *,
-                       t_tile, k, d_tile):
-    """int8-table variant: same row DMAs, plus an in-VMEM dequant.
-
-    The fetched rows stay in their 1-byte storage dtype through the DMA;
-    dequantization is one multiply by the per-row scale on the VMEM tile
-    (DESIGN.md §13).  Scales ride the scalar-prefetch path next to the
-    indices — (T, k) float32 pre-gathered per fetched row, so the kernel
-    reads t_tile*k SMEM scalars, never the (m,) scale vector.
-    """
-    t0 = pl.program_id(0) * t_tile
-    d0 = pl.program_id(1) * d_tile
-    copies = []
     for tt in range(t_tile):
+        total = None
         for j in range(k):
-            row = idx_ref[t0 + tt, j]
-            c = pltpu.make_async_copy(
-                table_ref.at[pl.ds(row, 1), pl.ds(d0, d_tile)],
-                rows.at[pl.ds(tt * k + j, 1), :],
-                sems.at[tt * k + j],
-            )
-            c.start()
-            copies.append(c)
-    for c in copies:
-        c.wait()
-    s = jnp.stack([jnp.stack([s_ref[t0 + tt, j] for j in range(k)])
-                   for tt in range(t_tile)])             # (t_tile, k) f32
-    r = rows[...].astype(jnp.float32).reshape(t_tile, k, d_tile)
-    out_ref[...] = (r * s[:, :, None]).sum(axis=1).astype(out_ref.dtype)
+            e = tt * k + j
+            x = pick_row(blk, e, offs[e])
+            if scaled:
+                x = x * s_ref[(t0 + tt) * k + j]
+            total = x if total is None else total + x
+        acc[tt:tt + 1, :] = total
+    out_ref[...] = acc[...].astype(out_ref.dtype)
 
 
 def _embed_fwd(table, idx, t_tile, d_tile, interpret, scales=None,
                out_dtype=None):
     m, D = table.shape
     T, k = idx.shape
-    t_tile = min(t_tile, T)
-    d_tile = min(d_tile, D)
     out_dtype = table.dtype if out_dtype is None else jnp.dtype(out_dtype)
-    table = pad_axis(table, 1, d_tile)
+    t_tile = min(max(t_tile, sublane_rows(out_dtype)), T)
+    d_tile = min(d_tile, D)
+    rows = sublane_rows(table.dtype)
+    table = pad_axis(pad_axis(table, 1, d_tile), 0, rows)
     idx = pad_axis(idx, 0, t_tile)             # pad rows gather row 0: sliced
     Tp, Dp = idx.shape[0], table.shape[1]
     grid = (Tp // t_tile, Dp // d_tile)
-
-    if scales is None:
-        kernel = functools.partial(_fwd_kernel, t_tile=t_tile, k=k,
-                                   d_tile=d_tile)
-        n_prefetch, operands = 1, (idx, table)
-        out_index = lambda t, d, idx_ref: (t, d)
-    else:
+    kernel = functools.partial(_fwd_kernel, t_tile=t_tile, k=k,
+                               d_tile=d_tile, rows=rows,
+                               scaled=scales is not None)
+    # flat (Tp*k,) indices: a 1-D SMEM operand is not lane-padded
+    operands = [idx.reshape(-1)]
+    if scales is not None:
         # Per-fetched-row scales, gathered OUTSIDE the kernel (a (T, k)
         # float32 gather of the (m,) vector — tiny next to the row DMAs)
         # so they prefetch alongside the indices.
-        sg = jnp.take(scales.astype(jnp.float32), idx, axis=0)   # (Tp, k)
-        kernel = functools.partial(_fwd_kernel_scaled, t_tile=t_tile, k=k,
-                                   d_tile=d_tile)
-        n_prefetch, operands = 2, (idx, sg, table)
-        out_index = lambda t, d, idx_ref, s_ref: (t, d)
+        operands.append(jnp.take(scales.astype(jnp.float32), idx,
+                                 axis=0).reshape(-1))
+    n_prefetch = len(operands)
+    out_index = lambda t, d, *prefetch: (t, d)
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=n_prefetch,
             grid=grid,
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((t_tile, d_tile), out_index),
             scratch_shapes=[
-                pltpu.VMEM((t_tile * k, d_tile), table.dtype),
+                pltpu.VMEM((t_tile * k, rows, d_tile), table.dtype),
+                pltpu.VMEM((t_tile, d_tile), jnp.float32),
                 pltpu.SemaphoreType.DMA((t_tile * k,)),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((Tp, Dp), out_dtype),
         interpret=interpret,
-    )(*operands)
+    )(*operands, table)
     return out[:T, :D]
 
 

@@ -9,6 +9,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Default m-tile of the blocked backward kernels (bloom_embed_bwd_pallas,
 # bloom_decode_bwd_pallas).  benchmarks/bench_kernels.py imports this to
@@ -21,6 +23,46 @@ def resolve_interpret(interpret: bool | None) -> bool:
     if interpret is None:
         return jax.default_backend() != "tpu"
     return bool(interpret)
+
+
+def sublane_rows(dtype) -> int:
+    """Rows of one native TPU tile of `dtype`: 8 for 32-bit, 16 for 16-bit,
+    32 for 8-bit elements.  A block or DMA slice along the second-minor
+    axis must cover whole tiles."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def fetch_row_blocks(src_ref, row_ids, blk, sems, d0, d_tile, rows,
+                     gate=None):
+    """Start one DMA per id in ``row_ids`` (scalars) of the ``rows``-row
+    aligned block of HBM ``src_ref`` that holds that row, columns
+    ``[d0, d0 + d_tile)``, into ``blk[e]`` of a (n, rows, d_tile) VMEM
+    scratch.  Mosaic moves whole sublane tiles only, so a single-row DMA
+    is refused; ``rows`` is sublane_rows(src dtype) and src's row count a
+    multiple of it.  ``gate(e)`` (traced bool) skips an entry's DMA.
+    Returns (in-block row offsets, copies); wait on the copies (under the
+    same gate) before reading ``blk``."""
+    offs, copies = [], []
+    for e, r in enumerate(row_ids):
+        start = pl.multiple_of((r // rows) * rows, rows)
+        c = pltpu.make_async_copy(
+            src_ref.at[pl.ds(start, rows), pl.ds(d0, d_tile)],
+            blk.at[e], sems.at[e])
+        if gate is None:
+            c.start()
+        else:
+            pl.when(gate(e))(c.start)
+        offs.append(r - start)
+        copies.append(c)
+    return offs, copies
+
+
+def pick_row(blk, e, off):
+    """(1, d_tile) float32 row ``off`` of the fetched block ``blk[e]`` — a
+    masked sublane sum (exactly one row matches, so values are copied)."""
+    x = blk[e].astype(jnp.float32)
+    sub = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.sum(jnp.where(sub == off, x, 0.0), axis=0, keepdims=True)
 
 
 def resolve_bwd_impl(bwd_impl: str, e_tile: int | None) -> tuple[str, int]:
@@ -58,7 +100,7 @@ def onehot_count(ids: jnp.ndarray, n: int, base=0) -> jnp.ndarray:
     """
     rows, k = ids.shape
     iota = jax.lax.broadcasted_iota(jnp.int32, (rows, n), 1) + base
-    w = (iota == ids[:, 0][:, None]).astype(jnp.float32)
+    w = (iota == ids[:, 0:1]).astype(jnp.float32)
     for j in range(1, k):
-        w = w + (iota == ids[:, j][:, None]).astype(jnp.float32)
+        w = w + (iota == ids[:, j:j + 1]).astype(jnp.float32)
     return w

@@ -37,7 +37,7 @@ from jax.sharding import PartitionSpec as P
 from repro import configs
 from repro.configs.base import SHAPE_BY_NAME, TrainConfig
 from repro.launch import roofline, steps
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import auto_mesh, make_production_mesh
 from repro.launch.sharding import (DistContext, batch_pspecs, cache_pspecs,
                                    opt_state_pspecs, param_pspecs)
 from repro.models import transformer as tf
@@ -110,9 +110,6 @@ def compile_variant(cfg, shape, dist, tc: TrainConfig, zero: bool = False):
 
 def _collect(compiled, n_devices):
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        # older jax (<=0.4.x) returns a one-element list of the cost dict
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     colls = roofline.parse_collectives(compiled.as_text(), n_devices)
     return {
@@ -151,10 +148,9 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         return {"arch": arch, "shape": shape_name, "skipped": reason}
 
     if mesh_shape is not None:
-        mesh = jax.make_mesh(tuple(mesh_shape),
-                             ("data", "model")[-len(mesh_shape):]
-                             if len(mesh_shape) == 2
-                             else ("pod", "data", "model"))
+        mesh = auto_mesh(mesh_shape,
+                         ("data", "model") if len(mesh_shape) == 2
+                         else ("pod", "data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     dist = DistContext(mesh)

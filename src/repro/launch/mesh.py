@@ -7,13 +7,24 @@ device while the dry-run forces 512 placeholder devices via XLA_FLAGS.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.  jax 0.9's
+    make_mesh defaults to Explicit axes, under which the model code's
+    gathers need sharding annotations it does not carry; Auto leaves the
+    partitioning to GSPMD as the sharding rules expect."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 two pods (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_local_mesh(axes=("data", "model")):
@@ -21,7 +32,7 @@ def make_local_mesh(axes=("data", "model")):
     tests and the CPU train/serve drivers."""
     n = jax.device_count()
     shape = (1,) * (len(axes) - 1) + (n,)
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_serving_mesh(n_hosts: int | None = None, model_parallel: int = 1):
@@ -37,8 +48,8 @@ def make_serving_mesh(n_hosts: int | None = None, model_parallel: int = 1):
         n_hosts = total // model_parallel
     assert n_hosts * model_parallel <= total, (
         f"need {n_hosts * model_parallel} devices, have {total}")
-    return jax.make_mesh((n_hosts, model_parallel), ("data", "model"),
-                         devices=jax.devices()[:n_hosts * model_parallel])
+    return auto_mesh((n_hosts, model_parallel), ("data", "model"),
+                     devices=jax.devices()[:n_hosts * model_parallel])
 
 
 def make_elastic_mesh(n_devices: int, axes=("data", "model"),
@@ -47,4 +58,4 @@ def make_elastic_mesh(n_devices: int, axes=("data", "model"),
     scale): keeps `model_parallel` fixed and gives the rest to data."""
     assert n_devices % model_parallel == 0
     shape = (n_devices // model_parallel, model_parallel)
-    return jax.make_mesh(shape, axes[-2:])
+    return auto_mesh(shape, axes[-2:])

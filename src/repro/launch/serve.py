@@ -40,6 +40,7 @@ import numpy as np
 
 from repro import configs
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, make_serving_mesh
 from repro.launch.sharding import DistContext
 from repro.models import encdec as encdec_lib
@@ -332,6 +333,7 @@ def run_retrieval(preset: str = "smoke", slots: int = 4,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=("lm", "retrieval"), default="lm",
                     help="'lm' = token generation (default); 'retrieval' "

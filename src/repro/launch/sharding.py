@@ -28,24 +28,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
 
-try:  # jax>=0.6 stabilized shard_map
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The "skip replication check" kwarg was renamed check_rep -> check_vma
-# across jax versions; resolve it from the actual signature so either
-# jaxlib works (same dance as models/moe.py).
-import inspect as _inspect
-
-_CHECK_KW = ("check_vma" if "check_vma"
-             in _inspect.signature(_shard_map).parameters else "check_rep")
-
-
 def shard_map_nocheck(fn, mesh, in_specs, out_specs):
-    """shard_map with the replication check disabled, version-portable."""
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: False})
+    """shard_map with the replication check disabled."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def replicated_specs(tree):

@@ -288,7 +288,30 @@ def make_slot_decode_step(cfg: ModelConfig, topk: int = 16, dist=None):
         return {"caches": out["caches"], "topk_scores": scores,
                 "topk_ids": ids}
 
-    return step
+    if dist is None or cfg.io_impl != "pallas":
+        return step
+    # GSPMD cannot partition a Mosaic kernel, so the Pallas-IO pool step
+    # runs per data shard: every slot row is independent and the params
+    # are replicated, so each shard decodes its own slots with the
+    # single-device step.
+    from repro.launch.sharding import shard_map_nocheck, slot_pool_pspecs
+    from jax.sharding import PartitionSpec as P
+    if dist.n_model != 1:
+        raise NotImplementedError(
+            "io_impl='pallas' pool decode runs per data shard; a model "
+            f"axis of {dist.n_model} would have to split the Mosaic kernels")
+    local = make_slot_decode_step(cfg, topk=topk)
+    rows = P(dist.batch_axes)
+
+    def sharded_step(params, token, caches, pos, active):
+        pool = slot_pool_pspecs(cfg, caches, dist, token.shape[0])
+        return shard_map_nocheck(
+            local, dist.mesh, in_specs=(P(), rows, pool, rows, rows),
+            out_specs={"caches": pool, "topk_scores": rows,
+                       "topk_ids": rows},
+        )(params, token, caches, pos, active)
+
+    return sharded_step
 
 
 def make_retrieval_prefill_step(rcfg):

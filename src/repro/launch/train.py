@@ -39,6 +39,7 @@ from repro.configs.base import TrainConfig
 from repro.data import synthetic
 from repro.data.pipeline import BatchIterator, lm_batches
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.launch.sharding import DistContext, param_pspecs
 from repro.checkpoint.checkpointer import Checkpointer
@@ -144,6 +145,7 @@ def run(arch: str, steps: int = 100, batch: int = 8, seq: int = 64,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True,
                     choices=list(configs.ARCH_NAMES))

@@ -31,11 +31,13 @@ import argparse
 import json
 
 from repro.configs.retrieval import get_retrieval_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.failpoints import FailPlan
 from repro.train import retrieval_trainer as rt
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--config", default="eval2k",
                     help="retrieval config preset (default: eval2k — "

@@ -160,7 +160,7 @@ def recover_topk_spec(spec: Optional[BloomSpec], logits: jnp.ndarray,
     ``jax.lax.top_k`` on a materialized score vector — the streaming
     oracle seeds each chunk merge with the running best (earlier = lower
     ids first in the concat), and the Pallas kernel folds tiles in
-    ascending vocab order with strictly-greater replacement.
+    ascending vocab order, taking the lowest id among equal scores.
 
     ``table_dtype`` (DESIGN.md §13, None = legacy f32) narrows the
     resident logp rows: the Pallas kernel stores them narrow in HBM and

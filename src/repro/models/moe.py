@@ -23,21 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.launch.sharding import shard_map_nocheck
 from repro.models import layers
-
-try:  # jax>=0.6 stabilized shard_map
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The "skip replication check" kwarg was renamed check_rep -> check_vma
-# across jax versions; resolve it from the actual signature so either
-# jaxlib works (the seed pinned check_vma and broke on jax 0.4.x).
-import inspect as _inspect
-
-_CHECK_KW = ("check_vma" if "check_vma"
-             in _inspect.signature(_shard_map).parameters else "check_rep")
-
 from jax.sharding import PartitionSpec as P
 
 
@@ -172,11 +159,10 @@ def moe_apply(params, x, cfg: ModelConfig, dist=None):
         wspec = P(model_ax, None, None)
         sspec = (None if shared is None
                  else jax.tree.map(lambda _: P(None, None), shared))
-        out, aux = _shard_map(
-            fn, mesh=mesh,
+        out, aux = shard_map_nocheck(
+            fn, mesh,
             in_specs=(xs, P(None, None), wspec, wspec, wspec, sspec),
             out_specs=(xs, P()),
-            **{_CHECK_KW: False},
         )(x_flat, params["router"], params["w_gate"], params["w_up"],
           params["w_down"], shared)
         return out.reshape(B, S, D), aux

@@ -466,7 +466,7 @@ class PrefillPool:
                         f"injected prefill fault: rid {req.rid} "
                         f"attempt {attempt} on worker {w}")
                 res = self.workers[w].prefill(req)
-            except Exception:
+            except PrefillFault:
                 self.stats["retries"] += 1
                 continue
             self.stats["wait_units"] += self._busy[w] - base

@@ -52,6 +52,7 @@ from repro.core import bloom as bloom_lib
 from repro.core import quant
 from repro.kernels.bloom_decode_topk import modeled_hbm_bytes
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import recommender as rec_lib
 from repro.serving import admission as admission_lib
 from repro.serving import engine as engine_lib
@@ -365,6 +366,7 @@ def _drill(rcfg: RetrievalConfig, n_requests: int, n_slots: int,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--config", default="web10m",
                     help="retrieval config preset (default: web10m — the "

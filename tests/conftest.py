@@ -7,6 +7,9 @@ import jax
 import numpy as np
 import pytest
 
+# checkout root: the cwd of the subprocess tests (PYTHONPATH=src is relative)
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def subprocess_env():
     """Clean env for driver subprocess tests.
@@ -16,8 +19,9 @@ def subprocess_env():
     strip: without JAX_PLATFORMS the child process probes for accelerator
     runtimes at import and hangs on CPU-only CI boxes.
     """
-    env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"}
-    for var in ("JAX_PLATFORMS", "XLA_FLAGS", "XLA_PYTHON_CLIENT_PREALLOCATE"):
+    env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"}
+    for var in ("HOME", "TMPDIR", "JAX_PLATFORMS", "XLA_FLAGS",
+                "XLA_PYTHON_CLIENT_PREALLOCATE"):
         if var in os.environ:
             env[var] = os.environ[var]
     env.setdefault("JAX_PLATFORMS", jax.default_backend())

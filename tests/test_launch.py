@@ -7,7 +7,7 @@ import sys
 import jax
 import pytest
 
-from conftest import subprocess_env
+from conftest import REPO_ROOT, subprocess_env
 
 from repro.launch.mesh import make_elastic_mesh, make_local_mesh
 
@@ -35,7 +35,7 @@ def test_dryrun_subprocess_smallest_cell(tmp_path):
          "mamba2-1.3b", "--shape", "long_500k", "--no-roofline",
          "--out", str(tmp_path)],
         capture_output=True, text=True, env=subprocess_env(),
-        cwd="/root/repo", timeout=420)
+        cwd=REPO_ROOT, timeout=420)
     assert r.returncode == 0, r.stdout + r.stderr
     arts = os.listdir(tmp_path)
     assert len(arts) == 1
@@ -49,3 +49,20 @@ def test_device_count_is_one_outside_dryrun():
     """Smoke tests must see the real device count (the XLA flag is only
     set inside launch/dryrun.py's own process)."""
     assert jax.device_count() == 1
+
+
+def test_compile_cache_placement(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it the cache goes to the checkout's fixed .jax_cache directory."""
+    from repro.launch import compile_cache
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: updates.append((name, value)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+    assert updates == []
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.enable_compile_cache()
+    assert path == os.path.join(REPO_ROOT, ".jax_cache")
+    assert updates == [("jax_compilation_cache_dir", path)]

@@ -196,6 +196,25 @@ def test_engine_prefill_retry_and_reject_via_failpoints(served):
     assert_slot_log_sound(engine._sched, N_SLOTS)
 
 
+def test_engine_prefill_crash_propagates(served, monkeypatch):
+    """Only the injected PrefillFault is retried: any other prefill error
+    (a kernel that fails to compile, a device fault) escapes Engine.run
+    instead of becoming retries and a REJECT with empty tokens."""
+    cfg = served["cfg"]
+    engine = Engine(cfg, served["engine"].params, n_slots=N_SLOTS,
+                    max_len=MAX_LEN, topk=4, prefill_workers=2)
+
+    def crash(req):
+        raise RuntimeError("prefill crashed")
+
+    for worker in engine.prefill_pool.workers:
+        monkeypatch.setattr(worker, "prefill", crash)
+    with pytest.raises(RuntimeError, match="prefill crashed"):
+        engine.run(mixed_length_workload(cfg.vocab, 10, seed=0))
+    assert engine.prefill_pool.stats["retries"] == 0
+    assert engine.prefill_pool.stats["rejects"] == 0
+
+
 def test_overload_sheds_and_degrades_without_recompiling(served):
     """ISSUE 10 on the single-host engine: a surge + slow_decode plan
     overloads the pool under an AdmissionPolicy; expired/over-bound

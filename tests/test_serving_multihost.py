@@ -37,7 +37,7 @@ import sys
 import numpy as np
 import pytest
 
-from conftest import subprocess_env
+from conftest import REPO_ROOT, subprocess_env
 
 from repro.serving import (AdmissionPolicy, CollectiveTransport, FailPlan,
                            LoadSpec, ReplicaDivergence, Request,
@@ -64,7 +64,7 @@ def report(tmp_path_factory):
         [sys.executable, "-m", "repro.serving.sim_multihost",
          "--out", str(out)],
         capture_output=True, text=True, env=env,
-        cwd="/root/repo", timeout=540)
+        cwd=REPO_ROOT, timeout=540)
     assert r.returncode == 0, r.stdout + r.stderr
     with open(out) as f:
         return json.load(f)

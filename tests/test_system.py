@@ -71,16 +71,16 @@ def test_serve_driver_generates_tokens():
 def test_train_driver_crash_and_resume(tmp_path):
     """Kill the driver mid-run via --fault-at, rerun, expect completion."""
     ck = str(tmp_path / "ck")
-    from conftest import subprocess_env
+    from conftest import REPO_ROOT, subprocess_env
     cmd = [sys.executable, "-m", "repro.launch.train", "--arch",
            "qwen1.5-0.5b", "--steps", "16", "--batch", "2", "--seq", "16",
            "--ckpt", ck]
     env = subprocess_env()
     r1 = subprocess.run(cmd + ["--fault-at", "10"], capture_output=True,
-                        text=True, env=env, cwd="/root/repo")
+                        text=True, env=env, cwd=REPO_ROOT)
     assert r1.returncode != 0 and "induced fault" in r1.stderr
     r2 = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                        cwd="/root/repo")
+                        cwd=REPO_ROOT)
     assert r2.returncode == 0, r2.stderr
     assert "resumed from step" in r2.stdout
     assert "trained" in r2.stdout
