@@ -1,0 +1,2 @@
+"""idle_share.lm: see ``_shared.idle_share``."""
+from bench.layer_metrics._shared import idle_share as read  # noqa: F401
