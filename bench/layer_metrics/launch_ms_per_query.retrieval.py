@@ -1,0 +1,8 @@
+"""Host time in launches per query, in ms: the ``repro.launch`` spans of
+the traced window over the queries prefilled in it."""
+from bench.layer_metrics import _program
+
+
+def read(ctx):
+    return _program.per_query(ctx, _program.ms(_program.named(
+        ctx, "repro.launch")))

@@ -33,16 +33,6 @@ indices in-kernel).  All byte widths are single-sourced from dtype
 itemsize (core.quant.table_itemsize / ndarray.dtype.itemsize) — no bare
 ``* 2`` / ``* 4`` literals — so a storage-dtype change cannot silently
 desync the models.
-
-``--measure`` additionally wall-clocks each forward kernel numeric check
-(jit warmup, then best-of-N around jax.block_until_ready) into
-``measured_us`` / ``model_vs_measured`` fields.  On this CPU box the
-kernels execute in interpret mode at the clamped check shapes, so the
-numbers only bound sanity (the model is production-shape HBM time); on a
-real TPU the same flag produces the backing measurement.  The fields are
-informational — ``--check`` never gates on them — and the committed
-baseline is generated WITHOUT ``--measure``.  Run through
-``benchmarks/measure_env.sh`` for a quiet allocator/thread environment.
 """
 from __future__ import annotations
 
@@ -50,7 +40,6 @@ import argparse
 import json
 import pathlib
 import sys
-import time
 
 import jax
 import jax.numpy as jnp
@@ -110,30 +99,6 @@ QUANT_TOPK_SWEEP = (("bfloat16", "bf16"), ("int8", "int8"),
                     ("fp8_e4m3", "fp8"))
 
 
-def _measure_us(fn, repeats: int = 3) -> float:
-    """Best-of-N wall-clock of ``fn()`` in microseconds.
-
-    One untimed call first (jit compile + Bloom cache warmup), then N
-    timed calls around jax.block_until_ready — the informational
-    ``--measure`` numbers (module docstring; never CI-gated).
-    """
-    jax.block_until_ready(fn())
-    best = float("inf")
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn())
-        best = min(best, time.perf_counter() - t0)
-    return 1e6 * best
-
-
-def _measured(row: dict, fn) -> dict:
-    """Attach measured_us / model_vs_measured to a bench row in place."""
-    us = _measure_us(fn)
-    row["measured_us"] = round(us, 1)
-    row["model_vs_measured"] = round(row["tpu_us_model"] / us, 6)
-    return row
-
-
 def _cases():
     # (name, d, m, k, D, tokens)
     return [
@@ -154,7 +119,7 @@ def _max_err(a, b):
                          - jnp.asarray(b, jnp.float32)).max())
 
 
-def run(quick: bool = True, measure: bool = False):
+def run(quick: bool = True):
     rows = []
     key = jax.random.PRNGKey(0)
     for name, d, m, k, D, T in _cases():
@@ -177,8 +142,6 @@ def run(quick: bool = True, measure: bool = False):
         bytes_fwd = T * (k * D * its_tbl + D * its_tbl) + T * k * its_idx
         row = _row(f"{name}.embed.fwd", T, bytes_fwd,
                    _max_err(got, want), check_tokens=Tc)
-        if measure:
-            _measured(row, lambda: ops.bloom_embed(table, tokens, spec))
         rows.append(row)
 
         # ---- embed fwd, quantized tables (DESIGN.md §13): the same
@@ -219,10 +182,6 @@ def run(quick: bool = True, measure: bool = False):
             row = _row(f"{name}.embed.fwd.{alias}", T, bytes_q,
                        _max_err(got, want), check_tokens=Tc,
                        table_dtype=td, table_bytes=table_bytes, **extra)
-            if measure:
-                _measured(row, lambda td=td: bloom_embed_pallas(
-                    tbl_master, idx, table_dtype=td,
-                    out_dtype=jnp.float32))
             rows.append(row)
 
         # ---- embed bwd: blocked one-hot contraction.  The kernel sweeps
@@ -400,8 +359,6 @@ def run(quick: bool = True, measure: bool = False):
             + B * TOPK * IS_TOPK_PAIR
         row = _row(f"{name}.decode_topk", B, bytes_fused, err,
                    topk=TOPK, hbm_ratio=bytes_then / bytes_fused)
-        if measure:
-            _measured(row, lambda: bloom_decode_topk_pallas(logp, H, TOPK))
         rows.append(row)
 
         # ---- quantized fused decode-topk (DESIGN.md §13): the logp pool
@@ -440,10 +397,6 @@ def run(quick: bool = True, measure: bool = False):
             row = _row(f"{name}.decode_topk.{alias}", B, bytes_q, err,
                        topk=TOPK, table_dtype=td, inkernel_hash=True,
                        **extra)
-            if measure:
-                _measured(row, lambda td=td: bloom_decode_topk_pallas(
-                    logp, None, TOPK, table_dtype=td,
-                    hash_spec=(d, k, spec.seed)))
             rows.append(row)
 
         # ---- serving pool: row-skipping decode-topk vs slot occupancy ----
@@ -554,10 +507,6 @@ def write_json(rows, path=JSON_PATH, quick=True):
     if not quick:
         raise ValueError("the committed baseline is generated with --quick "
                          "only; rerun with quick=True")
-    # measured wall-clock is machine-dependent — never committed
-    rows = [{k: v for k, v in r.items()
-             if k not in ("measured_us", "model_vs_measured")}
-            for r in rows]
     payload = {
         "generated_by": "PYTHONPATH=src python -m benchmarks.bench_kernels"
                         " --quick",
@@ -677,19 +626,13 @@ def main():
     ap.add_argument("--check", action="store_true",
                     help="compare against committed BENCH_kernels.json and "
                          "fail on max_err / hbm_ratio regressions")
-    ap.add_argument("--measure", action="store_true",
-                    help="wall-clock the forward kernels (warmup + "
-                         "block_until_ready, best of 3) into measured_us "
-                         "/ model_vs_measured fields — informational, "
-                         "never gated, never committed; use "
-                         "benchmarks/measure_env.sh for env hygiene")
     args = ap.parse_args()
     if args.check and not args.quick:
         # the committed baseline records --quick check shapes; comparing
         # full-run max_err against it would validate mismatched shapes
         ap.error("--check requires --quick (the baseline is "
                  "--quick-generated)")
-    rows = run(quick=args.quick, measure=args.measure)
+    rows = run(quick=args.quick)
     for row in rows:
         print(row)
     if args.check:

@@ -22,13 +22,6 @@ drift of the deterministic fields or if the continuous/static decode-step
 speedup falls below MIN_SPEEDUP (the ISSUE-2 acceptance bar).  (No
 --quick mode: the whole sim IS the quick mode — one seeded workload per
 arch, ~15 s on CPU.)
-
-``--measure`` wall-clocks one warm full-occupancy retrieval decode step
-per retrieval case (jit warmup, best of 3 around block_until_ready) into
-``measured_us`` / ``model_vs_measured`` fields — the same informational,
-never-gated, never-committed contract as bench_kernels (wall_s
-precedent); run through ``benchmarks/measure_env.sh`` for a quiet
-allocator/thread environment.
 """
 from __future__ import annotations
 
@@ -36,14 +29,10 @@ import argparse
 import json
 import pathlib
 import sys
-import time
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 from repro import configs
-from repro.kernels.bloom_decode_topk import modeled_hbm_bytes
 from repro.launch import steps as steps_lib
 from repro.serving import (AdmissionPolicy, Engine, FailPlan, LoadSpec,
                            RetrievalEngine, RetrievalLoadSpec,
@@ -56,7 +45,6 @@ from repro.serving import (AdmissionPolicy, Engine, FailPlan, LoadSpec,
 JSON_PATH = pathlib.Path(__file__).resolve().parent.parent / \
     "BENCH_serving.json"
 MIN_SPEEDUP = 1.5
-HBM_BW = 819e9     # TPU-v5e HBM bandwidth (matches bench_kernels)
 # retrieval.* rows: the streaming decode must model at least this many
 # times fewer HBM bytes than the dense-table oracle (ISSUE-7 acceptance
 # bar at d=1M; the actual ratios are orders of magnitude above it)
@@ -315,20 +303,8 @@ def _run_surge_case(n_hosts: int, slots_per_host: int, n_requests: int,
     }
 
 
-def _measure_us(fn, repeats: int = 3) -> float:
-    """Best-of-N wall-clock of ``fn()`` in microseconds (one untimed
-    warmup call first — jit compile + Bloom cache build)."""
-    jax.block_until_ready(fn())
-    best = float("inf")
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn())
-        best = min(best, time.perf_counter() - t0)
-    return 1e6 * best
-
-
 def _run_retrieval_case(name: str, n_slots: int, n_requests: int,
-                        seed: int, measure: bool = False):
+                        seed: int):
     rcfg = configs.get_retrieval_config(name)
     load = RetrievalLoadSpec(n_requests=n_requests, catalog=rcfg.d,
                              c_max=rcfg.c_max, rate=2.0, seed=seed)
@@ -348,22 +324,6 @@ def _run_retrieval_case(name: str, n_slots: int, n_requests: int,
     mb = engine.modeled_bytes
     ratio = round(mb["dense_oracle_bytes"]
                   / max(mb["streaming_bytes"], 1), 1)
-    measured = {}
-    if measure:
-        # one warm full-occupancy decode step: the modeled HBM time of
-        # that step vs its wall clock (informational — on CPU the step
-        # is the jitted XLA streaming oracle, on TPU the Pallas kernel)
-        step = jax.jit(steps_lib.make_retrieval_decode_step(rcfg))
-        pool = jax.nn.log_softmax(jax.random.normal(
-            jax.random.PRNGKey(seed), (n_slots, rcfg.m)), axis=-1)
-        active = jnp.ones((n_slots,), bool)
-        us = _measure_us(lambda: step(pool, active))
-        step_bytes = modeled_hbm_bytes(
-            np.ones(n_slots, bool), rcfg.b_tile, m=rcfg.m, d=rcfg.d,
-            k=rcfg.k, topk=rcfg.topk)
-        model_us = 1e6 * step_bytes / HBM_BW
-        measured = {"measured_us": round(us, 1),
-                    "model_vs_measured": round(model_us / us, 6)}
     return {
         "bench": "serving", "name": f"retrieval.{name}",
         "d": rcfg.d, "m": rcfg.m, "k": rcfg.k, "topk": rcfg.topk,
@@ -383,16 +343,15 @@ def _run_retrieval_case(name: str, n_slots: int, n_requests: int,
         "bytes_ratio": ratio,
         # informational only (CPU wall time — never checked)
         "wall_s": round(st.wall_s, 3),
-        **measured,
     }
 
 
-def run(measure: bool = False):
+def run():
     rows = []
     for arch, n_slots, n_requests, seed in CASES:
         rows.extend(_run_case(arch, n_slots, n_requests, seed))
     for case in RETRIEVAL_CASES:
-        rows.append(_run_retrieval_case(*case, measure=measure))
+        rows.append(_run_retrieval_case(*case))
     for case in SHARDED_CASES:
         rows.append(_run_sharded_case(*case))
     for case in SHARDED_KILL_CASES:
@@ -446,10 +405,6 @@ CHECKED_FIELDS = ("decode_steps", "slot_steps_total", "slot_steps_active",
 
 
 def write_json(rows, path=JSON_PATH):
-    # measured wall-clock is machine-dependent — never committed
-    rows = [{k: v for k, v in r.items()
-             if k not in ("measured_us", "model_vs_measured")}
-            for r in rows]
     payload = {
         "generated_by": "PYTHONPATH=src python -m benchmarks.bench_serving",
         "min_speedup": MIN_SPEEDUP,
@@ -528,13 +483,8 @@ def main():
     ap.add_argument("--check", action="store_true",
                     help="compare against committed BENCH_serving.json; "
                          "fail on schedule drift or speedup regression")
-    ap.add_argument("--measure", action="store_true",
-                    help="also wall-clock one warm full-occupancy "
-                         "retrieval decode step per retrieval case "
-                         "(informational; never gated, never committed "
-                         "— run through benchmarks/measure_env.sh)")
     args = ap.parse_args()
-    rows = run(measure=args.measure)
+    rows = run()
     for row in rows:
         print(row)
     if args.check:

@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.configs.base import ModelConfig
 from repro.launch import steps as steps_lib
 from repro.models import io as io_lib
@@ -456,26 +457,27 @@ class PrefillPool:
         records only the attempt that completed — the failure-free path
         is step-for-step identical to the pre-retry pool.  Returns None
         once the attempt cap is exhausted (the REJECT path)."""
-        for attempt in range(PREFILL_MAX_ATTEMPTS):
-            w = (w0 + attempt) % self.n_workers
-            try:
-                if (self.failpoints is not None
-                        and self.failpoints.prefill_attempt_fails(
-                            req.rid, attempt)):
-                    raise PrefillFault(
-                        f"injected prefill fault: rid {req.rid} "
-                        f"attempt {attempt} on worker {w}")
-                res = self.workers[w].prefill(req)
-            except PrefillFault:
-                self.stats["retries"] += 1
-                continue
-            self.stats["wait_units"] += self._busy[w] - base
-            self._busy[w] += float(req.prompt_len)
-            self.stats["per_worker"][w] += 1
-            self.stats["jobs"] += 1
-            return res
-        self.stats["rejects"] += 1
-        return None
+        with tracing.span("prefill", rid=req.rid, items=req.prompt_len):
+            for attempt in range(PREFILL_MAX_ATTEMPTS):
+                w = (w0 + attempt) % self.n_workers
+                try:
+                    if (self.failpoints is not None
+                            and self.failpoints.prefill_attempt_fails(
+                                req.rid, attempt)):
+                        raise PrefillFault(
+                            f"injected prefill fault: rid {req.rid} "
+                            f"attempt {attempt} on worker {w}")
+                    res = self.workers[w].prefill(req)
+                except PrefillFault:
+                    self.stats["retries"] += 1
+                    continue
+                self.stats["wait_units"] += self._busy[w] - base
+                self._busy[w] += float(req.prompt_len)
+                self.stats["per_worker"][w] += 1
+                self.stats["jobs"] += 1
+                return res
+            self.stats["rejects"] += 1
+            return None
 
     def drain(self) -> List[Optional[Tuple[object, int]]]:
         """Dispatch every queued job FIFO to the earliest-available

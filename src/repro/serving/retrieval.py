@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.configs.retrieval import RetrievalConfig, get_retrieval_config
 from repro.core import bloom as bloom_lib
 from repro.core import quant
@@ -130,10 +131,14 @@ class RetrievalProgram(SlotProgram):
     def prefill(self, params, req: Request, device=None):
         items = np.full((1, self.rcfg.c_max), -1, np.int32)
         items[0, :req.prompt_len] = np.asarray(req.prompt, np.int32)
-        x = jnp.asarray(items)
-        if device is not None:
-            x = jax.device_put(x, device)
-        return self._prefill(params, x)[0], None
+        with tracing.span("h2d", what="items", bytes=items.nbytes):
+            x = jnp.asarray(items)
+            if device is not None:
+                x = jax.device_put(x, device)
+        with tracing.span("launch", fn="prefill", rid=req.rid):
+            rows = self._prefill(params, x)
+        with tracing.span("launch", fn="row", rid=req.rid):
+            return rows[0], None
 
     # -- decode half ---------------------------------------------------
     def check_admit(self, req: Request) -> None:
@@ -154,7 +159,10 @@ class RetrievalProgram(SlotProgram):
                stats: ServeStats) -> bool:
         row, first = payload
         assert first is None, "oneshot prefill emits no token"
-        state.pool = self._insert(state.pool, row, jnp.int32(req.slot))
+        with tracing.span("h2d", what="slot", bytes=4):
+            slot = jnp.int32(req.slot)
+        with tracing.span("launch", fn="insert", rid=req.rid):
+            state.pool = self._insert(state.pool, row, slot)
         state.live[req.slot] = True
         return True
 
@@ -167,8 +175,12 @@ class RetrievalProgram(SlotProgram):
         self._stage = stage
 
     def step(self, params, state: _RetrievalState):
-        active = jnp.asarray(state.live)
-        scores, ids = self._stage_decodes[self._stage](state.pool, active)
+        live = int(state.live.sum())
+        with tracing.span("h2d", what="live", bytes=state.live.nbytes):
+            active = jnp.asarray(state.live)
+        with tracing.span("launch", fn="decode", live=live):
+            scores, ids = self._stage_decodes[self._stage](state.pool,
+                                                           active)
         # bytes model follows the table_dtype knob (DESIGN.md §13): a
         # quantized decode stores the logp rows narrow, rehashes
         # in-kernel (no (d, k) stream) and — int8 only — reads one f32
@@ -182,7 +194,14 @@ class RetrievalProgram(SlotProgram):
             logp_itemsize=quant.table_itemsize(td),
             inkernel_hash=td is not None,
             row_scales=td == "int8")
-        return np.asarray(ids), np.asarray(scores)
+        # the device's wait, so that the copies below time the copy only
+        with tracing.span("wait", live=live):
+            jax.block_until_ready((scores, ids))
+        with tracing.span("d2h", what="ids", bytes=ids.nbytes):
+            ids_np = np.asarray(ids)
+        with tracing.span("d2h", what="scores", bytes=scores.nbytes):
+            scores_np = np.asarray(scores)
+        return ids_np, scores_np
 
     def emit(self, state: _RetrievalState, req: Request, slot: int, out,
              stats: ServeStats) -> bool:
