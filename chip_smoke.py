@@ -235,7 +235,7 @@ def retrieval_phase(preset=RETRIEVAL, slots=8, requests=16, n_check=4,
     spec = rcfg.spec()
     with Phase("retrieval.oracle"):
         for r in served[:n_check]:
-            row, _ = program.prefill(params, r)
+            (row, _), _ = program.prefill(params, r)
             logp = jax.nn.log_softmax(row.astype(jnp.float32))[None]
             _, ids = bloom_lib.decode_topk(spec, logp, rcfg.topk,
                                            chunk=rcfg.chunk)

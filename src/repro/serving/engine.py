@@ -359,10 +359,10 @@ class PrefillWorker:
     the serving topology — DESIGN.md §8).
 
     The worker emits whatever its program's prefill emits — (caches,
-    first_token) for the LM program (default), (logits_row, None) for
-    the one-shot retrieval program; the caller inserts the payload into
-    its decode pool (for the sharded pool that insert is the
-    device-to-device transfer out of the prefill slice).  Splitting
+    first_token) for the LM program (default), ((logits_row, slot),
+    None) for the one-shot retrieval program; the caller inserts the
+    payload into its decode pool (for the sharded pool that insert is
+    the device-to-device transfer out of the prefill slice).  Splitting
     prefill out of the engine is what lets the sharded engine place it
     on its own slice while the decode pool spans the data axis; the
     single-host engines use the same worker unpinned, so both paths run

@@ -9,8 +9,9 @@ per-slot program differs (engine.SlotProgram):
 
   * prefill (``RetrievalProgram``): the request's padded item-id set is
     Bloom-encoded (core.bloom.encode, Eq. 1) and pushed through a small
-    FF tower (models/recommender.py) to an m-dim logits row — that row
-    IS the slot payload (no KV cache, no first token);
+    FF tower (models/recommender.py) to an m-dim logits row — that row,
+    with the slot it goes to, IS the slot payload (no KV cache, no
+    first token);
   * decode (``steps.make_retrieval_decode_step``): ONE occupancy-aware
     streaming Eq. 3 top-k over the whole catalog
     (io.recover_topk_spec), after which every served slot retires —
@@ -89,12 +90,20 @@ class _RetrievalState:
 
 
 class RetrievalProgram(SlotProgram):
-    """The one-shot retrieval slot program (see module doc): prefill
-    emits ``(logits_row, None)`` — there is no first token, the slot's
-    whole output comes from the single recover step.  The decode half
-    (constructed with ``n_slots``) owns the (n_slots, m) logits pool and
-    the one occupancy-aware streaming Eq. 3 top-k step over the catalog,
-    after which every served slot retires (``oneshot``)."""
+    """The one-shot retrieval slot program (see module doc).
+
+    Prefill packs the request's -1-padded items and its admitted slot
+    into one (1, c_max + 1) int32 host array, the slot in the last
+    column, and uploads it once; one jitted call runs the tower and
+    returns the (m,) logits row and the slot as a device int32 scalar.
+    It emits ``((row, slot), None)`` — there is no first token, the
+    slot's whole output comes from the single recover step.  ``insert``
+    hands both device values to the jitted pool write, so a query
+    dispatches two device programs and no eager op between them.  The
+    decode half (constructed with ``n_slots``) owns the (n_slots, m)
+    logits pool and the one occupancy-aware streaming Eq. 3 top-k step
+    over the catalog, after which every served slot retires
+    (``oneshot``)."""
 
     kind = "oneshot"
     oneshot = True
@@ -105,7 +114,13 @@ class RetrievalProgram(SlotProgram):
                  admission_policy=None):
         self.rcfg = rcfg
         self.n_slots = n_slots
-        self._prefill = jax.jit(steps_lib.make_retrieval_prefill_step(rcfg))
+        tower = steps_lib.make_retrieval_prefill_step(rcfg)
+
+        def prefill_row(params, packed):
+            """(1, c_max + 1) items and slot -> (m,) row, int32 slot."""
+            return tower(params, packed[:, :-1])[0], packed[0, -1]
+
+        self._prefill = jax.jit(prefill_row)
         if n_slots is None:
             return                      # prefill-only program
         self._decode = jax.jit(steps_lib.make_retrieval_decode_step(rcfg))
@@ -129,16 +144,13 @@ class RetrievalProgram(SlotProgram):
 
     # -- prefill half --------------------------------------------------
     def prefill(self, params, req: Request, device=None):
-        items = np.full((1, self.rcfg.c_max), -1, np.int32)
-        items[0, :req.prompt_len] = np.asarray(req.prompt, np.int32)
-        with tracing.span("h2d", what="items", bytes=items.nbytes):
-            x = jnp.asarray(items)
-            if device is not None:
-                x = jax.device_put(x, device)
+        packed = np.full((1, self.rcfg.c_max + 1), -1, np.int32)
+        packed[0, :req.prompt_len] = req.prompt
+        packed[0, -1] = req.slot
+        with tracing.span("h2d", what="items", bytes=packed.nbytes):
+            x = jax.device_put(packed, device)
         with tracing.span("launch", fn="prefill", rid=req.rid):
-            rows = self._prefill(params, x)
-        with tracing.span("launch", fn="row", rid=req.rid):
-            return rows[0], None
+            return self._prefill(params, x), None
 
     # -- decode half ---------------------------------------------------
     def check_admit(self, req: Request) -> None:
@@ -157,10 +169,8 @@ class RetrievalProgram(SlotProgram):
 
     def insert(self, state: _RetrievalState, req: Request, payload,
                stats: ServeStats) -> bool:
-        row, first = payload
+        (row, slot), first = payload
         assert first is None, "oneshot prefill emits no token"
-        with tracing.span("h2d", what="slot", bytes=4):
-            slot = jnp.int32(req.slot)
         with tracing.span("launch", fn="insert", rid=req.rid):
             state.pool = self._insert(state.pool, row, slot)
         state.live[req.slot] = True
