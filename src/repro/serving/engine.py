@@ -192,11 +192,14 @@ def build_stage_decodes(stage0, topk: int,
 class _LMState:
     """Device-resident LM slot-pool state: the KV-cache pool plus the
     (tokens, pos, active) slot vectors that stay on device for the whole
-    run (host writes only on admit/retire events — see module doc)."""
+    run (host writes only on admit/retire events — see module doc).
+    ``live`` is the host's copy of ``active``, kept on the same events,
+    so a step's spans can say how many rows it decodes."""
     caches: object
     tokens: object
     pos: object
     active: object
+    live: np.ndarray
 
 
 class LMSlotProgram(SlotProgram):
@@ -274,12 +277,20 @@ class LMSlotProgram(SlotProgram):
     # -- prefill half --------------------------------------------------
     def prefill(self, params, req: Request, device=None):
         """req -> (caches at prompt length, greedy first token id)."""
-        prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
+        rid = req.rid
+        with tracing.span("h2d", what="prompt", bytes=4 * req.prompt_len):
+            prompt = jnp.asarray(req.prompt, jnp.int32)
+        with tracing.span("launch", fn="expand", rid=rid):
+            prompt = prompt[None, :]
         if device is not None:
             prompt = jax.device_put(prompt, device)
-        pre = self._prefill(params, {"tokens": prompt})
-        _, ids = self._recover(pre["last_logits"])
-        return pre["caches"], int(np.asarray(ids)[0, 0])
+        with tracing.span("launch", fn="prefill", rid=rid):
+            pre = self._prefill(params, {"tokens": prompt})
+        with tracing.span("launch", fn="recover", rid=rid):
+            _, ids = self._recover(pre["last_logits"])
+        with tracing.span("d2h", what="first", bytes=ids.nbytes):
+            ids = np.asarray(ids)
+        return pre["caches"], int(ids[0, 0])
 
     # -- decode half ---------------------------------------------------
     def check_admit(self, req: Request) -> None:
@@ -298,26 +309,39 @@ class LMSlotProgram(SlotProgram):
             caches=jax.tree.map(jnp.copy, self._pool_template),
             tokens=jnp.zeros((self.n_slots, 1), jnp.int32),
             pos=jnp.zeros((self.n_slots,), jnp.int32),
-            active=jnp.zeros((self.n_slots,), bool))
+            active=jnp.zeros((self.n_slots,), bool),
+            live=np.zeros((self.n_slots,), bool))
 
     def reset_slots(self, state: _LMState) -> None:
         state.tokens = jnp.zeros((self.n_slots, 1), jnp.int32)
         state.pos = jnp.zeros((self.n_slots,), jnp.int32)
         state.active = jnp.zeros((self.n_slots,), bool)
+        state.live[:] = False
 
     def insert(self, state: _LMState, req: Request, payload,
                stats: ServeStats) -> bool:
         small, first = payload
-        state.caches = self._insert(state.caches, small,
-                                    jnp.int32(req.slot))
+        rid = req.rid
+        with tracing.span("h2d", what="slot", bytes=4):
+            slot = jnp.int32(req.slot)
+        with tracing.span("launch", fn="insert", rid=rid):
+            state.caches = self._insert(state.caches, small, slot)
         req.tokens.append(first)
         stats.tokens_out += 1
         if self.stopped(req, first):
             return False
-        # admit event: the only h2d update of the slot state
-        state.tokens, state.pos, state.active = self._set_slot(
-            state.tokens, state.pos, state.active, jnp.int32(req.slot),
-            jnp.int32(first), jnp.int32(req.prompt_len))
+        # admit event: the only h2d update of the slot state, three
+        # scalars each uploaded (and converted) on its own
+        with tracing.span("h2d", what="slot", bytes=4):
+            slot = jnp.int32(req.slot)
+        with tracing.span("h2d", what="token", bytes=4):
+            tok = jnp.int32(first)
+        with tracing.span("h2d", what="pos", bytes=4):
+            pos = jnp.int32(req.prompt_len)
+        with tracing.span("launch", fn="set_slot", rid=rid):
+            state.tokens, state.pos, state.active = self._set_slot(
+                state.tokens, state.pos, state.active, slot, tok, pos)
+        state.live[req.slot] = True
         return True
 
     def set_stage(self, stage: int) -> None:
@@ -329,8 +353,11 @@ class LMSlotProgram(SlotProgram):
         self._stage = stage
 
     def step(self, params, state: _LMState):
-        out = self._stage_decodes[self._stage](
-            params, state.tokens, state.caches, state.pos, state.active)
+        live = int(state.live.sum())
+        with tracing.span("launch", fn="decode", live=live):
+            out = self._stage_decodes[self._stage](
+                params, state.tokens, state.caches, state.pos,
+                state.active)
         state.caches = out["caches"]
         # steady-state decode: tokens/pos advance on device from the
         # step's own outputs — no host round-trip re-upload.  The d2h
@@ -338,9 +365,17 @@ class LMSlotProgram(SlotProgram):
         # retirement host-side).  The [:, :1] slice happens OUTSIDE
         # _advance so a degraded stage's narrower top-k never re-traces
         # it (the jit always sees a (B, 1) operand).
-        state.tokens, state.pos = self._advance(
-            out["topk_ids"][:, :1], state.tokens, state.pos, state.active)
-        return np.asarray(out["topk_ids"][:, 0])
+        with tracing.span("launch", fn="slice_next", live=live):
+            nxt = out["topk_ids"][:, :1]
+        with tracing.span("launch", fn="advance", live=live):
+            state.tokens, state.pos = self._advance(
+                nxt, state.tokens, state.pos, state.active)
+        with tracing.span("launch", fn="slice_top1", live=live):
+            top1 = out["topk_ids"][:, 0]
+        with tracing.span("wait", live=live):
+            top1.block_until_ready()
+        with tracing.span("d2h", what="ids", bytes=top1.nbytes):
+            return np.asarray(top1)
 
     def emit(self, state: _LMState, req: Request, slot: int, out,
              stats: ServeStats) -> bool:
@@ -348,7 +383,11 @@ class LMSlotProgram(SlotProgram):
         req.tokens.append(tok)
         stats.tokens_out += 1
         if self.stopped(req, tok):
-            state.active = self._drop_slot(state.active, jnp.int32(slot))
+            with tracing.span("h2d", what="slot", bytes=4):
+                idx = jnp.int32(slot)
+            with tracing.span("launch", fn="drop", rid=req.rid):
+                state.active = self._drop_slot(state.active, idx)
+            state.live[slot] = False
             return True
         return False
 
