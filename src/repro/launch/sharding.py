@@ -238,7 +238,9 @@ def cache_pspecs(cfg: ModelConfig, caches, dist: DistContext,
         s = _path_str(path)
         nd = len(leaf.shape)
         # leading dim is the stacked layer dim (n_super)
-        if "attn" in s or "cross" in s:   # (L, B, T, KV, hd)
+        if "attn" in s:                   # (L, B, KV, hd, T)
+            return P(None, bx, kv_ax, None, seq_axes_for(leaf.shape[4]))
+        if "cross" in s:                  # (L, B, T_enc, KV, hd)
             return P(None, bx, seq_axes_for(leaf.shape[2]), kv_ax, None)
         if "ssm" in s:                    # (L, B, H, N, P)
             return P(None, bx, m_ax, None, None)
@@ -290,7 +292,9 @@ def slot_pool_pspecs(cfg: ModelConfig, pool, dist: DistContext,
     def spec_for(path, leaf):
         s = _path_str(path)
         nd = len(leaf.shape)
-        if "attn" in s or "cross" in s:   # (L, B, T, KV, hd)
+        if "attn" in s:                   # (L, B, KV, hd, T)
+            return P(None, bx, kv_ax, None, None)
+        if "cross" in s:                  # (L, B, T_enc, KV, hd)
             return P(None, bx, None, kv_ax, None)
         if "ssm" in s:                    # (L, B, H, N, P)
             return P(None, bx, m_ax, None, None)

@@ -128,10 +128,11 @@ def insert_cache_slot(pool, caches_small, slot):
     """Write one request's prefill caches into batch slot `slot` of a
     preallocated cache pool.
 
-    Every cache leaf is stacked (n_layers, B, ...) — attention k/v carry a
-    sequence dim at axis 2 that may be SHORTER in the prefill caches than
-    in the pool (prompt_len < max_len); lax.dynamic_update_slice writes the
-    small block at (0, slot, 0, ...) and leaves the tail untouched.  Stale
+    Every cache leaf is stacked (n_layers, B, ...) — attention k/v are
+    (n_layers, B, KV, hd, T), their sequence dim at axis 4 SHORTER in the
+    prefill caches than in the pool (prompt_len < max_len);
+    lax.dynamic_update_slice writes the small block at (0, slot, 0, ...)
+    and leaves the tail untouched.  Stale
     tail entries from a previous occupant are never read: the kv validity
     mask only admits positions <= the slot's current offset, and decode
     overwrites each position before first attending to it.  SSM caches
@@ -273,6 +274,12 @@ def make_slot_decode_step(cfg: ModelConfig, topk: int = 16, dist=None):
     compiled step serving a pool whose requests were admitted at different
     times (no per-offset recompiles, no bucketing).  `active` masks the
     Eq. 3 vocabulary recovery so retired slots can never leak tokens.
+    The returned step's ``kv_write`` names how it writes its KV rows:
+    ``"inplace"`` on one device (the stacked pool carried through the
+    layer loop, one row written per slot and layer; also each shard of
+    the Pallas-IO step below), ``"masked"`` under a GSPMD ``dist`` (each
+    layer's cache a scan input and output, rewritten by an
+    ``iota == pos`` select).
     """
     apply_fn = apply_fn_for(cfg)
 
@@ -288,6 +295,7 @@ def make_slot_decode_step(cfg: ModelConfig, topk: int = 16, dist=None):
         return {"caches": out["caches"], "topk_scores": scores,
                 "topk_ids": ids}
 
+    step.kv_write = "inplace" if dist is None else "masked"
     if dist is None or cfg.io_impl != "pallas":
         return step
     # GSPMD cannot partition a Mosaic kernel, so the Pallas-IO pool step
@@ -311,6 +319,7 @@ def make_slot_decode_step(cfg: ModelConfig, topk: int = 16, dist=None):
                        "topk_ids": rows},
         )(params, token, caches, pos, active)
 
+    sharded_step.kv_write = local.kv_write
     return sharded_step
 
 

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels.kv_write import kv_write_rows_pallas
 from repro.models import layers
 
 NEG_INF = -1e30
@@ -73,7 +74,7 @@ def _gqa_split(q, num_kv: int):
     return q.reshape(B, S, num_kv, H // num_kv, hd)
 
 
-def _expand_heads(q, k, v, num_heads: int):
+def _expand_heads(q, k, v, num_heads: int, kv_axis: int = 2):
     """GQA -> MHA layout that PRESERVES tensor-parallel head sharding.
 
     §Perf iteration (qwen3-4b train_4k): reshaping q (B,S,H,hd) ->
@@ -84,13 +85,14 @@ def _expand_heads(q, k, v, num_heads: int):
     dim on every attention operand; the repeat itself is a cheap broadcast
     of the small kv tensors.
 
-    Returns q (B,S,H,1,hd), k/v (B,T,H,hd).
+    Returns q (B,S,H,1,hd), k/v (B,T,H,hd) — or, for a decode cache
+    (B,KV,hd,T) with ``kv_axis=1``, (B,H,hd,T).
     """
     B, S, H, hd = q.shape
-    rep = num_heads // k.shape[2]
+    rep = num_heads // k.shape[kv_axis]
     if rep > 1:
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
+        k = jnp.repeat(k, rep, axis=kv_axis)
+        v = jnp.repeat(v, rep, axis=kv_axis)
     return q.reshape(B, S, H, 1, hd), k, v
 
 
@@ -361,8 +363,8 @@ def self_attention_with_cache(params, cfg: ModelConfig, x, positions,
     B, S = x.shape[:2]
     o = o.reshape(B, S, cfg.num_heads, cfg.resolved_head_dim)
     out = jnp.einsum("bshk,hkd->bsd", o, params["wo"].astype(x.dtype))
-    return out, {"k": kv_k.astype(cache_dtype),
-                 "v": kv_v.astype(cache_dtype)}
+    return out, {"k": _cache_layout(kv_k).astype(cache_dtype),
+                 "v": _cache_layout(kv_v).astype(cache_dtype)}
 
 
 def cross_attention(params, cfg: ModelConfig, x, kv_x, q_positions,
@@ -385,26 +387,56 @@ def cross_attention(params, cfg: ModelConfig, x, kv_x, q_positions,
 
 def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int,
                   dtype=jnp.bfloat16):
+    """Zeroed KV cache, head-major with positions last: (B, KV, hd, T).
+
+    Positions lie on the lanes and a head's hd values on the sublanes, so
+    the layout the decode attention reads, the one its row write touches
+    and the device's default layout are one and the same (no relayout of
+    the cache, and no lane padding of a head dim under 128)."""
     hd = cfg.resolved_head_dim
     return {
-        "k": jnp.zeros((batch, max_len, cfg.num_kv_heads, hd), dtype),
-        "v": jnp.zeros((batch, max_len, cfg.num_kv_heads, hd), dtype),
+        "k": jnp.zeros((batch, cfg.num_kv_heads, hd, max_len), dtype),
+        "v": jnp.zeros((batch, cfg.num_kv_heads, hd, max_len), dtype),
     }
 
 
+def _cache_layout(a):
+    """(B, T, KV, hd) -> the cache's (B, KV, hd, T)."""
+    return a.transpose(0, 2, 3, 1)
+
+
+def _attend_cache(q, k, v, kv_valid):
+    """One query per row against a (B, KV, hd, T) cache: the arithmetic
+    of ``naive_attention`` (scores in the compute dtype, then masked
+    softmax in float32).  q: (B, KV, G, hd); kv_valid: (B, T).
+    Returns (B, KV, G, hd)."""
+    hd = q.shape[-1]
+    scores = jnp.einsum("bkgh,bkht->bkgt", q, k) / np.sqrt(hd)
+    scores = scores.astype(jnp.float32)
+    scores = jnp.where(kv_valid[:, None, None, :], scores, NEG_INF)
+    w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bkgt,bkht->bkgh", w, v)
+
+
 def decode_self_attention(params, cfg: ModelConfig, x, cache, pos,
-                          dist=None):
+                          dist=None, layer=None):
     """Single-token decode against a KV cache.
 
-    x: (B, 1, D); cache: {"k","v"} (B, T, KV, hd); pos: position of the
+    x: (B, 1, D); cache: {"k","v"} (B, KV, hd, T); pos: position of the
     new token (cache entries < pos are valid) — either a scalar int32
     (static batch: all rows at the same offset) or a (B,) int32 vector
     (continuous-batching slot pool: every slot decodes at its own
     sequence offset inside ONE compiled step).
+
+    With ``layer`` (an int32 index, possibly traced), ``cache`` is the
+    whole layer-stacked cache (L, B, KV, hd, T) carried through the
+    layer loop: each row's new (k, v) is written in place at (layer,
+    row, :, :, pos) and the attention reads that layer where it lies.
+    Without it, the cache is this layer's own (B, KV, hd, T) block.
     Returns (out (B, 1, D), new_cache).
     """
     B, _, D = x.shape
-    T = cache["k"].shape[1]
+    T = cache["k"].shape[-1]
     pos = jnp.asarray(pos)
     per_slot = pos.ndim == 1
     posb = (pos[:, None] if per_slot
@@ -423,39 +455,42 @@ def decode_self_attention(params, cfg: ModelConfig, x, cache, pos,
         rep = lambda a: dist.constrain(  # noqa: E731
             a, P(bx, *([None] * (a.ndim - 1))))
         q, k_new, v_new = rep(q), rep(k_new), rep(v_new)
-    if seq_sharded or per_slot:
+    new = {"k": k_new[:, 0], "v": v_new[:, 0]}          # (B, KV, hd)
+    if layer is not None:
+        # in place: one (KV, hd) column per row, at the row's own
+        # position; the stack is carried through the layer loop and
+        # donated through the step, so no other part of it moves
+        k, v = kv_write_rows_pallas(cache["k"], cache["v"], new["k"],
+                                    new["v"], layer,
+                                    jnp.broadcast_to(pos, (B,)))
+        cache = {"k": k, "v": v}
+        k_all, v_all = k[layer], v[layer]
+    elif seq_sharded or per_slot:
         # masked (iota == pos) write: fully elementwise.  Needed when the
         # cache is sequence-sharded (a positional dynamic write makes
         # GSPMD reshard the whole multi-GB cache) and when pos is a (B,)
-        # slot vector (each row writes a different offset — there is no
-        # single dynamic_update_slice for that).  Writes the exact same
-        # values as the slice path, so slot decode stays bit-identical to
-        # static decode per row.
-        sel = (jnp.arange(T)[None, :, None, None]
-               == posb.reshape(B, 1, 1, 1))
-        cache = {
-            "k": jnp.where(sel, k_new.astype(cache["k"].dtype),
-                           cache["k"]),
-            "v": jnp.where(sel, v_new.astype(cache["v"].dtype),
-                           cache["v"]),
-        }
+        # slot vector outside the carried loop (a sharded pool, the
+        # enc-dec decoder).  Writes the exact same values as the in-place
+        # path, so every path decodes the same tokens.
+        sel = jnp.arange(T)[None, None, None, :] == posb.reshape(B, 1, 1, 1)
+        cache = {n: jnp.where(sel, new[n].astype(c.dtype)[..., None], c)
+                 for n, c in cache.items()}
+        k_all, v_all = cache["k"], cache["v"]
     else:
         # unsharded/batch-sharded cache: write exactly one position.
-        cache = {
-            "k": jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k_new.astype(cache["k"].dtype), pos, axis=1),
-            "v": jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v_new.astype(cache["v"].dtype), pos, axis=1),
-        }
-    kv_pos = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
-    kv_valid = kv_pos <= posb
-    qg, k_all, v_all = _expand_heads(q, cache["k"].astype(x.dtype),
-                                     cache["v"].astype(x.dtype),
-                                     cfg.num_heads)
-    # decode reads the whole cache once -> bandwidth-bound; use the naive
-    # path (scores are (B, 1, H, T) — small) so XLA fuses mask+softmax.
-    o = naive_attention(qg, k_all, v_all, causal=False, q_pos=posb,
-                        kv_pos=kv_pos, kv_valid=kv_valid)
+        cache = {n: jax.lax.dynamic_update_slice_in_dim(
+            c, new[n].astype(c.dtype)[..., None], pos, axis=3)
+            for n, c in cache.items()}
+        k_all, v_all = cache["k"], cache["v"]
+    kv_valid = jnp.arange(T)[None, :] <= posb
+    # every path reads with the heads expanded, so a sharded H dim stays
+    # whole on every operand (_expand_heads) and all paths share one
+    # arithmetic; decode reads the whole cache once -> bandwidth-bound;
+    # scores are (B, H, 1, T) — small — so XLA fuses mask+softmax.
+    qg, k_all, v_all = _expand_heads(q, k_all.astype(x.dtype),
+                                     v_all.astype(x.dtype), cfg.num_heads,
+                                     kv_axis=1)
+    o = _attend_cache(qg[:, 0], k_all, v_all, kv_valid)
     o = o.reshape(B, 1, cfg.num_heads, cfg.resolved_head_dim)
     out = jnp.einsum("bshk,hkd->bsd", o, params["wo"].astype(x.dtype))
     return out, cache

@@ -9,7 +9,10 @@ policy wraps exactly one super-block.
 Modes:
   train    — full sequence, no caches (loss handled by the caller).
   prefill  — full sequence, emits decode caches + all-position logits.
-  decode   — one token against caches at position `pos`.
+  decode   — one token against caches at position `pos`.  On one device
+             the stacked caches are carried through the layer loop and
+             updated in place (kernels/kv_write.py); under a `dist` they
+             pass through the scan as per-layer inputs and outputs.
 """
 from __future__ import annotations
 
@@ -20,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.kv_write import pool_len
 from repro.models import attention, io, layers, mamba2, moe
 
 
@@ -100,7 +104,9 @@ def lm_init(key, cfg: ModelConfig):
 # --------------------------------------------------------------------------
 
 def _sublayer_apply(p, cfg: ModelConfig, j: int, x, positions, mode,
-                    cache, pos, dist):
+                    cache, pos, dist, layer=None):
+    """One sub-layer.  With ``layer`` (decode in place), ``cache`` holds
+    the whole layer-stacked caches and the result is their update."""
     mixer, ffn = sublayer_roles(cfg)[j]
     aux = jnp.zeros((), jnp.float32)
     new_cache = {}
@@ -114,7 +120,8 @@ def _sublayer_apply(p, cfg: ModelConfig, j: int, x, positions, mode,
             new_cache["attn"] = kv
         else:
             y, kv = attention.decode_self_attention(
-                p["attn"], cfg, h, cache["attn"], pos, dist=dist)
+                p["attn"], cfg, h, cache["attn"], pos, dist=dist,
+                layer=layer)
             new_cache["attn"] = kv
     else:
         if mode == "train":
@@ -123,10 +130,19 @@ def _sublayer_apply(p, cfg: ModelConfig, j: int, x, positions, mode,
             y, mc = mamba2.mamba_apply(p["mamba"], cfg, h,
                                        return_cache=True)
             new_cache["mamba"] = mc
-        else:
+        elif layer is None:
             y, mc = mamba2.mamba_decode_step(p["mamba"], cfg, h,
                                              cache["mamba"])
             new_cache["mamba"] = mc
+        else:
+            # recurrent state has no sequence axis: the layer's is
+            # replaced whole
+            y, mc = mamba2.mamba_decode_step(
+                p["mamba"], cfg, h,
+                jax.tree.map(lambda a: a[layer], cache["mamba"]))
+            new_cache["mamba"] = jax.tree.map(
+                lambda a, n: a.at[layer].set(n.astype(a.dtype)),
+                cache["mamba"], mc)
     x = x + y
     if dist is not None:
         x = dist.constrain_tokens(x)
@@ -143,13 +159,13 @@ def _sublayer_apply(p, cfg: ModelConfig, j: int, x, positions, mode,
 
 
 def _superblock_apply(bp, cfg: ModelConfig, x, positions, mode, cache,
-                      pos, dist):
+                      pos, dist, layer=None):
     auxes = jnp.zeros((), jnp.float32)
     new_caches = {}
     for j in range(period_of(cfg)):
         sub_c = cache.get(f"sub{j}") if cache is not None else None
         x, nc, aux = _sublayer_apply(bp[f"sub{j}"], cfg, j, x, positions,
-                                     mode, sub_c, pos, dist)
+                                     mode, sub_c, pos, dist, layer)
         if nc:
             new_caches[f"sub{j}"] = nc
         auxes = auxes + aux
@@ -168,12 +184,17 @@ def _remat(fn, cfg: ModelConfig):
 
 def init_lm_cache(cfg: ModelConfig, batch: int, cache_len: int,
                   dtype=jnp.bfloat16):
-    """Zeroed decode caches, stacked (n_super, ...) to match scanned blocks."""
+    """Zeroed decode caches, stacked (n_super, ...) to match scanned
+    blocks; attention k/v are (n_super, B, KV, hd, T).
+
+    T is ``cache_len`` rounded up to whole 128-lane windows, the unit
+    the in-place row write moves (``kernels.kv_write.pool_len``); the
+    positions past ``cache_len`` are never valid, so never attended."""
     sb = {}
     for j, (mixer, _) in enumerate(sublayer_roles(cfg)):
         if mixer == "attn":
             sb[f"sub{j}"] = {"attn": attention.init_kv_cache(
-                cfg, batch, cache_len, dtype)}
+                cfg, batch, pool_len(cache_len), dtype)}
         else:
             sb[f"sub{j}"] = {"mamba": mamba2.init_mamba_cache(
                 cfg, batch, dtype)}
@@ -223,7 +244,29 @@ def lm_apply(params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
         lambda bp, x, c: _superblock_apply(bp, cfg, x, positions, mode, c,
                                            pos, dist))
 
-    if cfg.scan_layers:
+    if mode == "decode" and dist is None:
+        # the stacked caches ride in the carry, so each layer updates
+        # them where they lie: no per-layer slice out of the stack, no
+        # restack into a second pool, and the donated input aliases the
+        # output
+        def body(carry, inp):
+            x, aux, c = carry
+            bp, i = inp
+            x, c, a = _superblock_apply(bp, cfg, x, positions, mode, c,
+                                        pos, dist, layer=i)
+            return (x, aux + a, c), None
+
+        n = num_superblocks(cfg)
+        if cfg.scan_layers:
+            (x, aux, new_caches), _ = jax.lax.scan(
+                body, (x, 0.0, caches), (params["blocks"], jnp.arange(n)))
+        else:
+            carry = (x, 0.0, caches)
+            for i in range(n):
+                carry, _ = body(carry, (jax.tree.map(
+                    lambda a: a[i], params["blocks"]), i))
+            x, aux, new_caches = carry
+    elif cfg.scan_layers:
         def body(carry, inp):
             x, aux = carry
             bp, c = inp

@@ -237,8 +237,10 @@ class LMSlotProgram(SlotProgram):
         # never reuses the previous tree, so XLA (where supported)
         # updates the multi-GB cache in place instead of allocating a
         # second pool and copying per step
-        self._decode = jax.jit(steps_lib.make_slot_decode_step(
-            cfg, topk=topk, dist=dist), donate_argnums=(2,))
+        decode = steps_lib.make_slot_decode_step(cfg, topk=topk, dist=dist)
+        # how the compiled step writes its KV rows, named on its span
+        self.kv_write = decode.kv_write
+        self._decode = jax.jit(decode, donate_argnums=(2,))
         # degrade ladder (DESIGN.md §14): one pre-built decode jit per
         # stage width; a DEGRADE/RESTORE swaps the dict entry in use.
         # Narrowing the served top-k never changes the emitted token —
@@ -354,7 +356,8 @@ class LMSlotProgram(SlotProgram):
 
     def step(self, params, state: _LMState):
         live = int(state.live.sum())
-        with tracing.span("launch", fn="decode", live=live):
+        with tracing.span("launch", fn="decode", live=live,
+                          kv_write=self.kv_write):
             out = self._stage_decodes[self._stage](
                 params, state.tokens, state.caches, state.pos,
                 state.active)

@@ -11,6 +11,8 @@ The topology is described inside a fixture, never at import time: only
 one process may load the TPU library, and every xdist worker imports
 this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -120,3 +122,96 @@ def test_ce_compiles(one_chip, direction):
     fn = loss if direction == "fwd" else jax.grad(
         lambda z, h: loss(z, h).sum())
     _compile(fn, one_chip, ((T, M), jnp.float32), ((T, K), jnp.int32))
+
+
+# -- the qwen1.5-0.5b slot-pool decode step, whole ----------------------
+
+POOL_SLOTS, POOL_LEN, POOL_TOPK = 32, 1_280, 8
+_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1,
+          "s8": 1, "u8": 1}
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+                    r"([\w\-]+)\(([^)]*)\)")
+_PARAM = re.compile(r"%([\w.\-]+): (\w+)\[([\d,]*)\]")
+
+
+def _nbytes(dtype, dims):
+    n = _BYTES.get(dtype, 4)
+    for d in filter(None, dims.split(",")):
+        n *= int(d)
+    return n
+
+
+def _moved(hlo_text):
+    """(op, instruction, bytes) of every copy, select and
+    dynamic-update-slice in the optimized HLO, fused or not: the bytes a
+    copy or select writes, and the size of the update a
+    dynamic-update-slice writes into its operand."""
+    size = {m.group(1): _nbytes(m.group(2), m.group(3))
+            for m in _PARAM.finditer(hlo_text)}
+    found = []
+    for line in hlo_text.splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, dtype, dims, op, operands = m.groups()
+        size[name] = _nbytes(dtype, dims)
+        if op in ("copy", "select"):
+            found.append((op, name, size[name]))
+        elif op == "dynamic-update-slice":
+            update = operands.split(",")[1].strip().lstrip("%")
+            found.append((op, name, size.get(update, size[name])))
+    return found
+
+
+def _pool_step(one_chip, monkeypatch, max_len):
+    """The served qwen1.5-0.5b pool step (32 slots, bfloat16 KV, Eq. 3
+    top-8) at ``max_len``, compiled for the described chip; returns it
+    and the bytes of one layer's k cache at ``max_len``."""
+    from repro import configs
+    from repro.kernels import kv_write
+    from repro.launch import steps as steps_lib
+    from repro.models import transformer as tf
+    # the backend here is the CPU: compile the row write as Mosaic
+    monkeypatch.setattr(kv_write, "resolve_interpret", lambda i: False)
+    cfg = configs.get_config("qwen1.5-0.5b")
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,  # noqa: E731
+                                           sharding=one_chip)
+    params = jax.eval_shape(lambda: steps_lib.cast_params_for_compute(
+        steps_lib.init_fn_for(cfg)(jax.random.PRNGKey(0)), cfg))
+    pool = jax.eval_shape(lambda: tf.init_lm_cache(
+        cfg, POOL_SLOTS, max_len, dtype=jnp.bfloat16))
+    args = (jax.tree.map(place, params),
+            place(jax.ShapeDtypeStruct((POOL_SLOTS, 1), jnp.int32)),
+            jax.tree.map(place, pool),
+            place(jax.ShapeDtypeStruct((POOL_SLOTS,), jnp.int32)),
+            place(jax.ShapeDtypeStruct((POOL_SLOTS,), jnp.bool_)))
+    compiled = jax.jit(steps_lib.make_slot_decode_step(cfg, topk=POOL_TOPK),
+                       donate_argnums=(2,)).lower(*args).compile()
+    layer_bytes = (POOL_SLOTS * max_len * cfg.num_kv_heads
+                   * cfg.resolved_head_dim * 2)
+    return compiled, layer_bytes
+
+
+def test_pool_decode_step_moves_no_pool(one_chip, monkeypatch):
+    """The served qwen1.5-0.5b pool step (32 slots x 1,280 positions,
+    bfloat16 KV, Eq. 3 top-8) writes its KV rows in place: no copy,
+    select or dynamic-update-slice moves a buffer the size of one
+    layer's cache or more, and the step needs under 1 GB of scratch
+    (a masked write over the scanned caches needed 4.87 GB)."""
+    compiled, layer_bytes = _pool_step(one_chip, monkeypatch, POOL_LEN)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert [m for m in _moved(text) if m[2] >= layer_bytes] == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_pool_decode_step_compiles_at_an_odd_length(one_chip, monkeypatch):
+    """A pool sized for 1,100 positions, no multiple of 128, is
+    allocated at 1,152 (whole 128-lane windows), so the row write still
+    moves one window per slot: the step compiles for the chip, moves no
+    layer-sized buffer and keeps its scratch under 1 GB."""
+    compiled, layer_bytes = _pool_step(one_chip, monkeypatch, 1_100)
+    text = compiled.as_text()
+    assert "bf16[24,32,16,64,1152]" in text
+    assert [m for m in _moved(text) if m[2] >= layer_bytes] == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

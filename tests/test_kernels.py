@@ -779,3 +779,44 @@ def test_model_quantized_pallas_matches_xla_fake_quant(table_dtype):
     ep = io_lib.embed_tokens(params["io"], cfg_p, toks)
     np.testing.assert_allclose(np.asarray(ex), np.asarray(ep),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("T", [384, 128])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_kv_write_rows_sweep(T, dtype):
+    """One (KV, hd) column per slot at (layer, b, :, :, pos[b]), the rest
+    of both pools untouched; a slot at pos == T (retired) writes nothing.
+    T = 384 is three 128-lane windows, T = 128 one."""
+    from repro.kernels.kv_write import kv_write_rows_pallas
+    L, B, KV, hd = 3, 5, 2, 16
+    ks = jax.random.split(KEY, 4)
+    k_pool = jax.random.normal(ks[0], (L, B, KV, hd, T), dtype)
+    v_pool = jax.random.normal(ks[1], (L, B, KV, hd, T), dtype)
+    k_new = jax.random.normal(ks[2], (B, KV, hd), jnp.float32)
+    v_new = jax.random.normal(ks[3], (B, KV, hd), jnp.float32)
+    pos = jnp.asarray([0, T - 1, 127 % T, T, 130 % T], jnp.int32)
+    layer = 1
+    got_k, got_v = jax.jit(lambda *a: kv_write_rows_pallas(
+        *a, interpret=True))(k_pool, v_pool, k_new, v_new, layer, pos)
+    for got, pool, new in ((got_k, k_pool, k_new), (got_v, v_pool, v_new)):
+        want = np.array(pool)
+        for b, p in enumerate(np.asarray(pos)):
+            if p < T:
+                want[layer, b, :, :, p] = np.asarray(new[b].astype(dtype))
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("n,want", [(1, 128), (40, 128), (128, 128),
+                                    (129, 256), (1_100, 1_152)])
+def test_kv_write_needs_whole_lane_windows(n, want):
+    """A pool holds whole 128-lane windows (``pool_len``); the row write
+    refuses any other length rather than move a whole row per slot."""
+    from repro.kernels.kv_write import kv_write_rows_pallas, pool_len
+    assert pool_len(n) == want
+    if n % 128 == 0:
+        return
+    pool = jnp.zeros((1, 2, 1, 8, n), jnp.float32)
+    new = jnp.zeros((2, 1, 8), jnp.float32)
+    with pytest.raises(ValueError, match=f"allocate pool_len.*{want}"):
+        kv_write_rows_pallas(pool, pool, new, new, 0,
+                             jnp.zeros((2,), jnp.int32), interpret=True)

@@ -107,8 +107,8 @@ def test_decode_matches_full_attention_last_position():
         cache_dtype=jnp.float32)
     cache = A.init_kv_cache(cfg, 2, S, dtype=jnp.float32)
     cache = {
-        "k": cache["k"].at[:, :S - 1].set(kv["k"]),
-        "v": cache["v"].at[:, :S - 1].set(kv["v"]),
+        "k": cache["k"].at[..., :S - 1].set(kv["k"]),
+        "v": cache["v"].at[..., :S - 1].set(kv["v"]),
     }
     dec, _ = A.decode_self_attention(params, cfg, x[:, S - 1:],
                                      cache, S - 1)

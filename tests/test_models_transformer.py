@@ -150,3 +150,83 @@ def test_dense_io_vs_bloom_io_shapes():
         toks = jax.random.randint(KEY, (1, 4), 0, 128)
         logits = tf.lm_apply(params, cfg, {"tokens": toks})["logits"]
         assert logits.shape[-1] == (64 if bloom else 128)
+
+
+POOL_SLOTS, POOL_LEN = 4, 32
+
+
+@pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "qwen3-4b", "mamba2-1.3b"])
+@pytest.mark.parametrize("case", ["staggered", "reused_slot"])
+def test_inplace_pool_decode_matches_masked(arch, case):
+    """The slot-pool decode on one device writes each slot's new KV row
+    in place (``kv_write`` "inplace"); under a ``dist`` (here a
+    one-device mesh) the same step rewrites the scanned caches by an
+    ``iota == pos`` select ("masked").  Step after step, with slots at
+    different positions, both give the same logits and caches.  In
+    ``reused_slot`` a slot is then re-admitted with a shorter prompt, so
+    its old occupant's positions lie past the new prompt: the slot's
+    logits equal those of the same request in a fresh pool, so that
+    stale tail is never read."""
+    from repro.launch import steps as steps_lib
+    from repro.launch.mesh import auto_mesh
+    from repro.launch.sharding import DistContext
+    cfg = configs.get_smoke_config(arch)
+    params = tf.lm_init(KEY, cfg)
+    dist = DistContext(auto_mesh((1, 1), ("data", "model"),
+                                 devices=jax.devices()[:1]))
+    paths = {"inplace": None, "masked": dist}
+    assert {w: steps_lib.make_slot_decode_step(cfg, dist=d).kv_write
+            for w, d in paths.items()} == {w: w for w in paths}
+    decode = {w: jax.jit(lambda c, tok, pos, d=d: tf.lm_apply(
+        params, cfg, {"tokens": tok}, mode="decode", caches=c, pos=pos,
+        dist=d)) for w, d in paths.items()}
+    prefill = jax.jit(lambda t: tf.lm_apply(params, cfg, {"tokens": t},
+                                            mode="prefill")["caches"])
+    insert = jax.jit(steps_lib.insert_cache_slot)
+    rng = np.random.default_rng(0)
+
+    def fresh():
+        return tf.init_lm_cache(cfg, POOL_SLOTS, POOL_LEN, dtype=jnp.float32)
+
+    def admit(pool, slot, prompt):
+        return insert(pool, prefill(jnp.asarray(prompt)[None]), slot)
+
+    def steps(pools, pos, n):
+        """n decode steps of every pool from positions ``pos``; the
+        logits of each step, per pool."""
+        logits = {w: [] for w in pools}
+        for i in range(n):
+            tok = jnp.asarray(rng.integers(0, cfg.vocab, (POOL_SLOTS, 1)),
+                              jnp.int32)
+            p = jnp.asarray(pos + i, jnp.int32)
+            for w in pools:
+                out = decode.get(w, decode["inplace"])(pools[w], tok, p)
+                pools[w] = out["caches"]
+                logits[w].append(np.asarray(out["logits"]))
+        return logits
+
+    def assert_paths_agree(pools, logits):
+        np.testing.assert_array_equal(logits["inplace"], logits["masked"])
+        for a, b in zip(jax.tree.leaves(pools["inplace"]),
+                        jax.tree.leaves(pools["masked"])):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    lens = np.array([5, 13, 3, 9])
+    pools = {w: fresh() for w in paths}
+    for slot, L in enumerate(lens):
+        prompt = rng.integers(0, cfg.vocab, L)
+        pools = {w: admit(p, slot, prompt) for w, p in pools.items()}
+    assert_paths_agree(pools, steps(pools, lens, 4))
+    if case == "staggered":
+        return
+
+    slot, short = 1, rng.integers(0, cfg.vocab, 4)
+    assert len(short) < lens[slot] + 4
+    pools = {w: admit(p, slot, short) for w, p in pools.items()}
+    pools["alone"] = admit(fresh(), slot, short)
+    pos = lens + 4
+    pos[slot] = len(short)
+    logits = steps(pools, pos, 4)
+    assert_paths_agree(pools, logits)
+    np.testing.assert_array_equal(np.asarray(logits["alone"])[:, slot],
+                                  np.asarray(logits["inplace"])[:, slot])

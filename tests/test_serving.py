@@ -120,6 +120,34 @@ def test_engine_rejects_overlong_request():
         engine.run([req])
 
 
+def test_odd_max_len_pool_holds_whole_lane_windows(served):
+    """``MAX_LEN`` (40) is no multiple of 128: the pool is allocated at
+    128 positions, the length its in-place row write moves, and still
+    admits only what fits ``MAX_LEN``.  A request that fills ``MAX_LEN``
+    to its last position, beside a shorter one, serves the tokens it
+    serves alone."""
+    cfg, params = served["cfg"], served["engine"].params
+    engine = Engine(cfg, params, n_slots=2, max_len=MAX_LEN, topk=4)
+    prog = engine.program
+    assert prog.kv_write == "inplace"
+    assert {a.shape[-1] for a in jax.tree.leaves(prog._pool_template)} \
+        == {128}
+    rng = np.random.default_rng(3)
+
+    def reqs():
+        return [Request(rid=i, prompt=rng.integers(0, cfg.vocab, n)
+                        .astype(np.int32), max_gen=g)
+                for i, (n, g) in enumerate(((MAX_LEN - 6, 6), (5, 4)))]
+
+    results, _ = engine.run(reqs())
+    solo = Engine(cfg, params, n_slots=1, max_len=MAX_LEN, topk=4)
+    rng = np.random.default_rng(3)
+    for req in reqs():
+        alone, _ = solo.run_static([req])
+        assert results[req.rid].tokens == alone[req.rid].tokens
+    assert len(results[0].tokens) == 6
+
+
 def test_prefill_pool_is_schedule_and_token_invariant(served):
     """Prefill pool satellite (DESIGN.md §9): a burst served through a
     3-worker pool produces the EXACT tokens of the 1-worker pool (and of

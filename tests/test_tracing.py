@@ -353,3 +353,35 @@ def test_lm_no_trace_records_nothing_and_serves_the_same(lm_served,
         caches = out["caches"]
         want.append(int(np.asarray(out["topk_ids"])[slot, 0]))
     assert off == want
+
+
+def test_lm_decode_span_names_its_kv_write(lm_served, tmp_path):
+    """The decode span says how the compiled pool step writes its KV
+    rows: in place on one device, by the masked select under a ``dist``
+    (a one-device mesh here).  Either way a step makes the same
+    launches, and the two serve the same tokens."""
+    from repro.launch.mesh import auto_mesh
+    from repro.launch.sharding import DistContext
+    from repro.serving.engine import LMSlotProgram
+    cfg, program, pool, params = lm_served
+    dist = DistContext(auto_mesh((1, 1), ("data", "model"),
+                                 devices=jax.devices()[:1]))
+    masked = LMSlotProgram(cfg, topk=LM_TOPK, n_slots=SLOTS,
+                           max_len=LM_MAX_LEN, dist=dist)
+    served = {}
+    for prog in (program, masked):
+        req = _lm_request(cfg, rid=11, slot=2, max_gen=3)
+        tokens, events = _lm_traced(
+            (cfg, prog, PrefillPool(cfg, params, topk=LM_TOPK,
+                                    program=prog), params),
+            tmp_path / prog.kv_write, req)
+        launches = [s[3] for s in events if s[0] == "repro.launch"
+                    and "live" in s[3]]
+        served[prog.kv_write] = tokens, launches
+    assert set(served) == {"inplace", "masked"}
+    for kv_write, (tokens, launches) in served.items():
+        assert [a["fn"] for a in launches] == [
+            "decode", "slice_next", "advance", "slice_top1"] * 2
+        assert [a.get("kv_write") for a in launches if a["fn"] == "decode"] \
+            == [kv_write] * 2
+    assert served["inplace"][0] == served["masked"][0]
