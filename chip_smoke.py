@@ -277,11 +277,12 @@ def four_chip_phase(arch=ARCH, full=True, slots_per_host=2,
                for d in jax.devices()]
     print("bytes_in_use per device: " + ", ".join(
         f"{d.id}:{b}" for d, b in zip(jax.devices(), per_dev)), flush=True)
-    pool_devs = {s.device for leaf in jax.tree.leaves(engine._pool_template)
+    pool = engine.program._pool_template
+    pool_devs = {s.device for leaf in jax.tree.leaves(pool)
                  for s in leaf.addressable_shards}
     check(len(pool_devs) == n_hosts,
           f"slot pool spans {len(pool_devs)} devices, not {n_hosts}")
-    compiles = engine._decode._cache_size()
+    compiles = engine.program._decode._cache_size()
     check(compiles == 1, f"sharded decode step compiled {compiles} times")
 
     # The reference pool decodes as many rows per step as one shard does.

@@ -23,10 +23,11 @@ loadgen.py      — deterministic Poisson arrival + length-mix workloads,
                   per-host streams pure in (seed, host_id)
 engine.py       — the slot-pool engine, the disaggregated PrefillPool
                   (FIFO over N mesh-slice workers), the SlotProgram
-                  per-slot program protocol, and the static-batching
-                  A/B baseline
-sharded_pool.py — data plane: data-axis-sharded slot pool, ShardedEngine,
-                  slot compaction
+                  per-slot program protocol, LMSlotProgram (the one LM
+                  data plane, sharded under a dist), and the
+                  static-batching A/B baseline
+sharded_pool.py — ShardedEngine: the sharded control plane driving
+                  LMSlotProgram over a data-axis-sharded slot pool
 retrieval.py    — web-scale one-shot Bloom retrieval over the same slot
                   pool: Zipf item lookups, streaming Eq. 3 top-k over a
                   10M+-item catalog, modeled-bytes audit vs the

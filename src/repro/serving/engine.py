@@ -44,9 +44,11 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro import tracing
 from repro.configs.base import ModelConfig
+from repro.launch import sharding as sharding_lib
 from repro.launch import steps as steps_lib
 from repro.models import io as io_lib
 from repro.models import transformer as tf
@@ -211,7 +213,8 @@ class LMSlotProgram(SlotProgram):
 
     A prefill-only instance (``PrefillWorker``'s default; the sharded
     engine's disaggregated prefill slice) omits ``max_len`` and never
-    builds the decode-side jits or the pool template."""
+    builds the decode-side jits or the pool template.  Under a ``dist``
+    it is also the sharded engine's data plane (DESIGN.md §8)."""
 
     kind = "lm"
     oneshot = False
@@ -233,6 +236,28 @@ class LMSlotProgram(SlotProgram):
         if max_len is None:
             return                      # prefill-only program
         assert n_slots is not None and n_slots >= 1 and max_len >= 2
+        template = tf.init_lm_cache(cfg, n_slots, max_len,
+                                    dtype=jnp.dtype(cfg.dtype))
+        # Under a ``dist`` the pool takes the serving specs and every jit
+        # returning pool or slot state pins that layout, so the decode
+        # step compiles once for a whole run matrix.
+        pool = row = tok = self._slot_layout = None
+        if dist is not None:
+            specs = sharding_lib.slot_pool_pspecs(cfg, template, dist,
+                                                  n_slots)
+            pool = jax.tree.map(dist.sharding, specs,
+                                is_leaf=lambda x: isinstance(x, P))
+            row = dist.sharding(P(dist.batch_axes))
+            tok = dist.sharding(P(dist.batch_axes, None))
+            template = jax.device_put(template, pool)
+            self._slot_layout = (tok, row, row)
+        self._pool_template = template
+        step_out = {"caches": pool, "topk_scores": tok, "topk_ids": tok}
+
+        def jit(fn, donate, out):
+            pin = {} if dist is None else {"out_shardings": out}
+            return jax.jit(fn, donate_argnums=donate, **pin)
+
         # the pool is donated through every decode/insert: the loop
         # never reuses the previous tree, so XLA (where supported)
         # updates the multi-GB cache in place instead of allocating a
@@ -240,7 +265,7 @@ class LMSlotProgram(SlotProgram):
         decode = steps_lib.make_slot_decode_step(cfg, topk=topk, dist=dist)
         # how the compiled step writes its KV rows, named on its span
         self.kv_write = decode.kv_write
-        self._decode = jax.jit(decode, donate_argnums=(2,))
+        self._decode = jit(decode, (2,), step_out)
         # degrade ladder (DESIGN.md §14): one pre-built decode jit per
         # stage width; a DEGRADE/RESTORE swaps the dict entry in use.
         # Narrowing the served top-k never changes the emitted token —
@@ -248,33 +273,45 @@ class LMSlotProgram(SlotProgram):
         self._stage = admission_lib.STAGE_NORMAL
         self._stage_decodes = build_stage_decodes(
             self._decode, topk, admission_policy,
-            lambda k: jax.jit(steps_lib.make_slot_decode_step(
-                cfg, topk=k, dist=dist), donate_argnums=(2,)))
-        self._insert = jax.jit(steps_lib.insert_cache_slot,
-                               donate_argnums=(0,))
-        self._pool_template = tf.init_lm_cache(
-            cfg, n_slots, max_len, dtype=jnp.dtype(cfg.dtype))
-        # (tokens, pos, active) live ON DEVICE for the whole run: the
-        # old loop rebuilt them host-side and re-uploaded all three
-        # every decode step (3 h2d transfers per token).  Steady-state
-        # decode advances them from the step's own outputs (_advance —
-        # next token and pos+1 for every slot that decoded, exactly
-        # what the host wrote back); the host touches them only on
-        # admit (_set_slot) and retire (_drop_slot) events.  Values are
-        # bit-identical to the host-side bookkeeping, so tokens are too.
-        self._advance = jax.jit(
+            lambda k: jit(steps_lib.make_slot_decode_step(
+                cfg, topk=k, dist=dist), (2,), step_out))
+        if dist is not None and dist.n_batch > 1:
+            per_shard = n_slots // dist.n_batch
+            self._insert = steps_lib.make_sharded_insert(specs, dist,
+                                                         per_shard)
+            self._compact = steps_lib.make_compact_pool(specs, dist,
+                                                        per_shard)
+        else:
+            self._insert = jit(steps_lib.insert_cache_slot, (0,), pool)
+            self._compact = jit(
+                lambda caches, perm: jax.tree.map(
+                    lambda buf: jnp.take(buf, perm, axis=1), caches),
+                (0,), pool)
+        # (tokens, pos, active) live ON DEVICE for the whole run, so no
+        # decode step uploads them.  Steady-state decode advances them
+        # from the step's own outputs (_advance — next token and pos+1
+        # for every slot that decoded); the host touches them only on
+        # admit (_set_slot), retire (_drop_slot), compaction and a
+        # host's death (_remap).  Values are bit-identical to host-side
+        # bookkeeping, so tokens are too.
+        self._advance = jit(
             lambda ids, tokens, pos, active: (
                 jnp.where(active[:, None], ids[:, :1], tokens),
                 pos + active.astype(pos.dtype)),
-            donate_argnums=(1, 2))
-        self._set_slot = jax.jit(
+            (1, 2), (tok, row))
+        self._set_slot = jit(
             lambda tokens, pos, active, slot, tok, p: (
                 tokens.at[slot, 0].set(tok), pos.at[slot].set(p),
                 active.at[slot].set(True)),
-            donate_argnums=(0, 1, 2))
-        self._drop_slot = jax.jit(lambda active, slot:
-                                  active.at[slot].set(False),
-                                  donate_argnums=(0,))
+            (0, 1, 2), self._slot_layout)
+        self._drop_slot = jit(lambda active, slot:
+                              active.at[slot].set(False), (0,), row)
+        # slot i takes slot perm[i]'s vectors, zeroed where ~keep
+        self._remap = jit(
+            lambda perm, keep, tokens, pos, active: (
+                jnp.where(keep[:, None], tokens[perm], 0),
+                jnp.where(keep, pos[perm], 0), active[perm] & keep),
+            (2, 3, 4), self._slot_layout)
 
     # -- prefill half --------------------------------------------------
     def prefill(self, params, req: Request, device=None):
@@ -307,33 +344,44 @@ class LMSlotProgram(SlotProgram):
         assert n_slots == self.n_slots
         # copy, not alias: the first donated insert/decode consumes its
         # input buffers, and the template must survive across runs
-        return _LMState(
+        state = _LMState(
             caches=jax.tree.map(jnp.copy, self._pool_template),
-            tokens=jnp.zeros((self.n_slots, 1), jnp.int32),
-            pos=jnp.zeros((self.n_slots,), jnp.int32),
-            active=jnp.zeros((self.n_slots,), bool),
+            tokens=None, pos=None, active=None,
             live=np.zeros((self.n_slots,), bool))
+        self.reset_slots(state)
+        return state
 
     def reset_slots(self, state: _LMState) -> None:
-        state.tokens = jnp.zeros((self.n_slots, 1), jnp.int32)
-        state.pos = jnp.zeros((self.n_slots,), jnp.int32)
-        state.active = jnp.zeros((self.n_slots,), bool)
+        n = self.n_slots
+        state.tokens, state.pos, state.active = jax.device_put(
+            (jnp.zeros((n, 1), jnp.int32), jnp.zeros((n,), jnp.int32),
+             jnp.zeros((n,), bool)), self._slot_layout)
         state.live[:] = False
 
     def insert(self, state: _LMState, req: Request, payload,
                stats: ServeStats) -> bool:
         small, first = payload
-        rid = req.rid
-        with tracing.span("h2d", what="slot", bytes=4):
-            slot = jnp.int32(req.slot)
-        with tracing.span("launch", fn="insert", rid=rid):
-            state.caches = self._insert(state.caches, small, slot)
+        self.insert_caches(state, req, small)
         req.tokens.append(first)
         stats.tokens_out += 1
         if self.stopped(req, first):
             return False
-        # admit event: the only h2d update of the slot state, three
-        # scalars each uploaded (and converted) on its own
+        self.start(state, req, first)
+        return True
+
+    def insert_caches(self, state: _LMState, req: Request, caches
+                      ) -> None:
+        """``insert``'s first half: the caches into ``req.slot``."""
+        with tracing.span("h2d", what="slot", bytes=4):
+            slot = jnp.int32(req.slot)
+        with tracing.span("launch", fn="insert", rid=req.rid):
+            state.caches = self._insert(state.caches, caches, slot)
+
+    def start(self, state: _LMState, req: Request, first: int) -> None:
+        """``insert``'s admit half: ``req.slot`` decodes from ``first``."""
+        rid = req.rid
+        # admit event: three scalars, each uploaded (and converted) on
+        # its own
         with tracing.span("h2d", what="slot", bytes=4):
             slot = jnp.int32(req.slot)
         with tracing.span("h2d", what="token", bytes=4):
@@ -344,7 +392,33 @@ class LMSlotProgram(SlotProgram):
             state.tokens, state.pos, state.active = self._set_slot(
                 state.tokens, state.pos, state.active, slot, tok, pos)
         state.live[req.slot] = True
-        return True
+
+    def drop(self, state: _LMState, slot: int, rid=None) -> None:
+        """Retire ``slot``: it stops decoding from the next step."""
+        with tracing.span("h2d", what="slot", bytes=4):
+            idx = jnp.int32(slot)
+        with tracing.span("launch", fn="drop", rid=rid):
+            state.active = self._drop_slot(state.active, idx)
+        state.live[slot] = False
+
+    def compact(self, state: _LMState, perm) -> None:
+        """Apply a COMPACT remap, ``perm[new] = old`` as
+        ``control.plan_compaction`` gives it, to the caches, the slot
+        vectors and ``live``."""
+        perm = np.asarray(perm, np.int32)
+        state.caches = self._compact(state.caches, perm)
+        self._remap_slots(state, perm, np.ones(self.n_slots, bool))
+
+    def clear(self, state: _LMState, lo: int, hi: int) -> None:
+        """Slots ``[lo, hi)`` stop decoding and hold a fresh pool's zero
+        token and position (a dead host's range, DESIGN.md §10)."""
+        slots = np.arange(self.n_slots, dtype=np.int32)
+        self._remap_slots(state, slots, (slots < lo) | (slots >= hi))
+
+    def _remap_slots(self, state: _LMState, perm, keep) -> None:
+        state.tokens, state.pos, state.active = self._remap(
+            perm, keep, state.tokens, state.pos, state.active)
+        state.live = state.live[perm] & keep
 
     def set_stage(self, stage: int) -> None:
         if stage not in self._stage_decodes:
@@ -386,11 +460,7 @@ class LMSlotProgram(SlotProgram):
         req.tokens.append(tok)
         stats.tokens_out += 1
         if self.stopped(req, tok):
-            with tracing.span("h2d", what="slot", bytes=4):
-                idx = jnp.int32(slot)
-            with tracing.span("launch", fn="drop", rid=req.rid):
-                state.active = self._drop_slot(state.active, idx)
-            state.live[slot] = False
+            self.drop(state, slot, req.rid)
             return True
         return False
 
@@ -723,9 +793,6 @@ class Engine:
                                         n_workers=prefill_workers,
                                         failpoints=self.failpoints,
                                         program=self.program)
-
-    def _stopped(self, req: Request, tok: int) -> bool:
-        return self.program.stopped(req, tok)
 
     # ------------------------------------------------------------------
     def run(self, requests: List[Request]

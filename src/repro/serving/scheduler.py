@@ -750,11 +750,12 @@ class ShardedScheduler:
 # ---------------------------------------------------------------------------
 
 class ScheduleClient:
-    """Data-plane hooks for ``run_schedule``.  The engine implements the
-    real pool (prefill pool, jitted decode, cache compaction); the
-    model-free simulation implements integer placeholders.  Sharing the
-    loop is what makes the engine's event log equal the simulation's by
-    construction — compaction decisions included."""
+    """Data-plane hooks for ``run_schedule``.  The sharded engine maps
+    them onto its ``LMSlotProgram`` (prefill pool, jitted decode, cache
+    compaction); the model-free simulation implements integer
+    placeholders.  Sharing the loop is what makes the engine's event log
+    equal the simulation's by construction — compaction decisions
+    included."""
 
     def prefill(self, reqs: List[Request]) -> List[Optional[int]]:
         """Admitted requests (in admission order) -> first token ids.
@@ -772,9 +773,6 @@ class ScheduleClient:
     def decode(self, active: Dict[int, Request]) -> Dict[int, int]:
         """One pool decode step -> token id per live slot."""
         raise NotImplementedError
-
-    def advance_slot(self, gslot: int, req: Request, tok: int) -> None:
-        """Per live slot after a decode step (token already appended)."""
 
     def stop_slot(self, gslot: int) -> None:
         """A live slot retired (release already recorded)."""
@@ -873,7 +871,6 @@ def run_schedule(sched: ShardedScheduler, client: ScheduleClient,
             tok = toks[gslot]
             req.tokens.append(tok)
             stats.tokens_out += 1
-            client.advance_slot(gslot, req, tok)
             if client.stopped(req, tok):
                 sched.release(gslot, now)
                 client.stop_slot(gslot)

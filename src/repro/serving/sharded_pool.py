@@ -1,15 +1,18 @@
-"""Data-axis-sharded serving: the *data plane* of the control/data-plane
-split (DESIGN.md §8/§9): GSPMD slot pool + disaggregated prefill pool +
-slot compaction.
+"""Data-axis-sharded serving: the sharded *control plane* of the
+control/data-plane split (DESIGN.md §8/§9), driving the one LM data
+plane, ``engine.LMSlotProgram``, built with the mesh's ``dist``.
 
-The PR-2 engine is single-host: its slot pool lives on the local mesh and
-admission is host-side Python.  This module shards exactly that boundary,
-the way production recommenders do (DLRM, Naumov et al. 2019):
+The single-host engine's slot pool lives on the local mesh and its
+admission is host-side Python.  This module shards exactly that
+boundary, the way production recommenders do (DLRM, Naumov et al.
+2019):
 
-  * **Sharded slot pool** — the cache tree is one GSPMD pytree whose slot
-    axis shards over the ``data`` mesh axis (`launch/sharding.
-    slot_pool_pspecs`): each data shard owns a contiguous slot range, so
+  * **Sharded slot pool** — the program places its cache tree by
+    ``launch/sharding.slot_pool_pspecs``: the slot axis shards over the
+    ``data`` mesh axis, each data shard owns a contiguous slot range, so
     decode reads are all-local and a cache insert touches one shard.
+    ``(tokens, pos, active)`` stay on the device and advance from each
+    step's own outputs, exactly as on one host.
   * **Transported admission** — scheduling is the replicated state
     machine of ``serving/control.py`` orchestrated by
     ``scheduler.ShardedScheduler``: arrival/release deltas travel a
@@ -21,24 +24,22 @@ the way production recommenders do (DLRM, Naumov et al. 2019):
   * **Disaggregated prefill pool** — prefill runs on
     ``engine.PrefillPool``: a FIFO scheduler over N single-device mesh
     slices, so a burst of arrivals no longer head-of-line blocks
-    admission behind one worker; the emitted caches are inserted into
-    the decode pool by ``steps.make_sharded_insert``, a shard_map whose
-    replicated-operand broadcast IS the device-to-device transfer.
+    admission behind one worker; the program's shard-local insert (a
+    shard_map whose replicated-operand broadcast IS the device-to-device
+    transfer) writes the emitted caches into the owning shard.
   * **Slot compaction** — with ``compact_threshold`` set, the control
     plane densifies fragmented host shards (``control.plan_compaction``)
-    and this engine applies the remap to the cache pytree via
-    ``steps.make_compact_pool`` (shard-local gather, donated in-place
-    update) and to the host-side token/pos/active arrays.  The densified
-    occupancy feeds ``bloom_decode_topk``'s prefetched row-skipping grid,
-    so a scattered pool recovers the dense pool's HBM bytes
-    (bench_kernels.py ``.decode_topk.scatter*`` rows, gated in CI).
+    and the program applies the remap to the cache pytree (a shard-local
+    gather, donated in-place update) and to its slot vectors.  The
+    densified occupancy feeds ``bloom_decode_topk``'s prefetched
+    row-skipping grid, so a scattered pool recovers the dense pool's HBM
+    bytes (bench_kernels.py ``.decode_topk.scatter*`` rows, gated in CI).
   * **ONE compiled decode step survives sharding AND compaction** — the
-    decode-pool step is the same ``steps.make_slot_decode_step``
-    per-slot-position jitted callable; out_shardings pin the donated
-    cache layout, and the compaction remap preserves it (out_specs ==
-    pool specs), so the executable never changes mid-run.  The multi-host
-    sim test asserts ``_decode._cache_size() == 1`` after a full
-    transport x compaction run matrix.
+    program's decode jit pins the donated cache layout to the pool
+    specs, and the compaction remap preserves it (out_specs == pool
+    specs), so the executable never changes mid-run.  The multi-host
+    sim test asserts ``program._decode._cache_size() == 1`` after a
+    full transport x compaction run matrix.
 
 Per-request tokens are BIT-identical to the single-host engine and to
 solo static serving — across both transports and with compaction on or
@@ -52,41 +53,36 @@ import time
 from typing import Dict, List, Optional, Tuple, Union
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.launch import sharding as sharding_lib
-from repro.launch import steps as steps_lib
-from repro.models import transformer as tf
-from repro.serving.admission import AdmissionPolicy
+from repro.serving.admission import AdmissionPolicy, STAGE_NORMAL
 from repro.serving.control import (CollectiveTransport, SimTransport,
                                    Transport)
-from repro.serving import engine as engine_lib
-from repro.serving.engine import Engine, PrefillPool, ServeStats
+from repro.serving.engine import (Engine, LMSlotProgram, PrefillPool,
+                                  ServeStats)
 from repro.serving.scheduler import (Request, ScheduleClient,
                                      ShardedScheduler, run_schedule)
 
 
 class _PoolClient(ScheduleClient):
-    """The real data plane behind ``run_schedule``: prefill-pool dispatch,
-    sharded cache inserts, the jitted pool decode step, and the
-    compaction remap.  The model-free ``_SimClient`` fills the same hooks
-    with placeholders — sharing the loop is what makes the engine's event
-    log equal the simulation's integer-for-integer."""
+    """``run_schedule``'s hooks as calls of the engine's ``LMSlotProgram``
+    on one run's device-resident state.  The model-free ``_SimClient``
+    fills the same hooks with placeholders — sharing the loop is what
+    makes the engine's event log equal the simulation's
+    integer-for-integer."""
 
     def __init__(self, engine: "ShardedEngine"):
         self.e = engine
-        self.stage = 0        # current degrade stage (DESIGN.md §14)
-        self.tokens = np.zeros((engine.n_slots, 1), np.int32)
-        self.pos = np.zeros((engine.n_slots,), np.int32)
-        self.active = np.zeros((engine.n_slots,), bool)
-        self.caches = engine._fresh_pool()
+        self.program = engine.program
+        # a run starts undegraded, whatever stage the last one ended in
+        self.program.set_stage(STAGE_NORMAL)
+        self.state = self.program.init_state(engine.n_slots)
 
     def prefill(self, reqs: List[Request]) -> List[Optional[int]]:
         for req in reqs:
-            engine_lib.assert_request_fits(req, self.e.max_len)
+            self.program.check_admit(req)
         firsts = []
         for req, res in zip(reqs,
                             self.e.prefill_pool.prefill_all(reqs)):
@@ -95,72 +91,42 @@ class _PoolClient(ScheduleClient):
                 # REJECTs the slot, which stays inactive
                 firsts.append(None)
                 continue
-            small, first = res
-            self.caches = self.e._insert(self.caches, small,
-                                         jnp.int32(req.slot))
+            caches, first = res
+            self.program.insert_caches(self.state, req, caches)
             firsts.append(first)
         return firsts
 
     def stopped(self, req: Request, tok: int) -> bool:
-        return self.e._stopped(req, tok)
+        return self.program.stopped(req, tok)
 
     def start_slot(self, req: Request, first: int) -> None:
-        self.tokens[req.slot, 0] = first
-        self.pos[req.slot] = req.prompt_len
-        self.active[req.slot] = True
+        self.program.start(self.state, req, first)
 
     def decode(self, active_map: Dict[int, Request]) -> Dict[int, int]:
-        e = self.e
-        out = e._stage_decodes[self.stage](
-            e.params,
-            jax.device_put(jnp.asarray(self.tokens), e._tok_sharding),
-            self.caches,
-            jax.device_put(jnp.asarray(self.pos), e._row_sharding),
-            jax.device_put(jnp.asarray(self.active), e._row_sharding))
-        self.caches = out["caches"]
-        ids = np.asarray(out["topk_ids"][:, 0])
+        # the step advances every live slot's token and pos on the device
+        ids = self.program.step(self.e.params, self.state)
         return {gslot: int(ids[gslot]) for gslot in active_map}
 
     def set_stage(self, stage: int) -> None:
-        if stage not in self.e._stage_decodes:
-            raise RuntimeError(
-                f"sharded pool: degrade stage {stage} was not pre-built "
-                "— construct the ShardedEngine with the run's "
-                "admission_policy (DESIGN.md §14)")
-        self.stage = stage
-
-    def advance_slot(self, gslot: int, req: Request, tok: int) -> None:
-        self.tokens[gslot, 0] = tok
-        self.pos[gslot] += 1
+        self.program.set_stage(stage)
 
     def stop_slot(self, gslot: int) -> None:
-        self.active[gslot] = False
+        self.program.drop(self.state, gslot)
 
     def compact(self, perm: List[int]) -> None:
-        p = np.asarray(perm, np.int32)
-        self.caches = self.e._compact(self.caches, p)
-        self.tokens = self.tokens[p]
-        self.pos = self.pos[p]
-        self.active = self.active[p]
+        self.program.compact(self.state, perm)
 
     def host_killed(self, host: int) -> None:
-        # the dead range stops decoding THIS step: clearing the active
-        # mask is the data plane's entire epoch change — decode is the
-        # occupancy-prefetched row-skipping grid, so surviving rows
-        # neither recompile (same shapes) nor change values (row
-        # independence); ≤1 recompile per epoch is trivially met at 0
+        # the dead range stops decoding THIS step: clearing its slots is
+        # the data plane's entire epoch change — surviving rows neither
+        # recompile (same shapes) nor change values (row independence)
         lo = host * self.e.slots_per_host
-        self.active[lo:lo + self.e.slots_per_host] = False
+        self.program.clear(self.state, lo, lo + self.e.slots_per_host)
 
     def host_down(self, host: int, reqs: List[Request]) -> None:
-        # death is visible cluster-wide: scrub the dead range's host-side
-        # state so the next occupant starts from the same zeros a fresh
-        # pool would (cache rows are overwritten by insert at admission)
-        lo = host * self.e.slots_per_host
-        hi = lo + self.e.slots_per_host
-        self.tokens[lo:hi] = 0
-        self.pos[lo:hi] = 0
-        self.active[lo:hi] = False
+        # death is visible cluster-wide: the range holds a fresh pool's
+        # zeros for its next occupant (insert overwrites the cache rows)
+        self.host_killed(host)
 
 
 class ShardedEngine:
@@ -235,57 +201,12 @@ class ShardedEngine:
                                         n_workers=prefill_workers,
                                         devices=devices)
 
-        # the sharded pool: slot axis over `data`
-        template = tf.init_lm_cache(cfg, self.n_slots, max_len,
-                                    dtype=jnp.dtype(cfg.dtype))
-        self._pool_specs = sharding_lib.slot_pool_pspecs(
-            cfg, template, self.dist, self.n_slots)
-        self._pool_shardings = jax.tree.map(
-            lambda spec: NamedSharding(mesh, spec), self._pool_specs,
-            is_leaf=lambda x: isinstance(x, P))
-        self._pool_template = jax.device_put(template, self._pool_shardings)
-
-        # per-step host->device commits: slot-aligned over `data`
-        self._row_sharding = NamedSharding(mesh, P(self.dist.batch_axes))
-        self._tok_sharding = NamedSharding(
-            mesh, P(self.dist.batch_axes, None))
-        # out_shardings pin the cache layout to the pool specs so the
-        # donated output of step t is a valid input of step t+1 with the
-        # SAME layout — otherwise GSPMD may pick a different output
-        # sharding and the second step recompiles (single-compiled-step
-        # invariant; the sim test asserts _decode._cache_size() == 1)
-        self._decode = jax.jit(
-            steps_lib.make_slot_decode_step(cfg, topk=topk, dist=self.dist),
-            donate_argnums=(2,),
-            out_shardings={"caches": self._pool_shardings,
-                           "topk_scores": self._tok_sharding,
-                           "topk_ids": self._tok_sharding})
-        # degrade ladder (DESIGN.md §14): pre-built narrower-top-k decode
-        # jits, same donation and sharding pins as the stage-0 step so a
-        # DEGRADE/RESTORE is a dict lookup — never a compile, never a
-        # layout change
-        self._stage_decodes = engine_lib.build_stage_decodes(
-            self._decode, topk, admission_policy,
-            lambda k: jax.jit(
-                steps_lib.make_slot_decode_step(cfg, topk=k,
-                                                dist=self.dist),
-                donate_argnums=(2,),
-                out_shardings={"caches": self._pool_shardings,
-                               "topk_scores": self._tok_sharding,
-                               "topk_ids": self._tok_sharding}))
-        self._insert = steps_lib.make_sharded_insert(
-            self._pool_specs, self.dist, slots_per_host)
-        self._compact = steps_lib.make_compact_pool(
-            self._pool_specs, self.dist, slots_per_host)
-
-    def _fresh_pool(self):
-        # copy, not alias: donation consumes the buffers (engine.py)
-        return jax.tree.map(jnp.copy, self._pool_template)
-
-    def _stopped(self, req: Request, tok: int) -> bool:
-        if self.eos_id is not None and tok == self.eos_id:
-            return True
-        return len(req.tokens) >= req.max_gen
+        # the data plane: the same LMSlotProgram the single-host engine
+        # runs, its pool placed by the slot-pool specs of `dist`
+        self.program = LMSlotProgram(
+            cfg, topk=topk, dist=self.dist, n_slots=self.n_slots,
+            max_len=max_len, eos_id=eos_id,
+            admission_policy=admission_policy)
 
     def _make_transport(self,
                         transport: Union[str, Transport]) -> Transport:

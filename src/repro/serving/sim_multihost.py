@@ -180,7 +180,7 @@ def run(seed: int = 0) -> dict:
         "prefill_workers": PREFILL_WORKERS,
         # compile count across the ENTIRE matrix: 4 engine runs through
         # both transports, with and without mid-flight cache remaps
-        "decode_compiles": engine._decode._cache_size(),
+        "decode_compiles": engine.program._decode._cache_size(),
         "prefill_stats": engine.prefill_pool.stats,
         "runs": runs,
         "sims": sims,
@@ -309,7 +309,7 @@ def run_chaos(seed: int = 0, failpoints: str | None = None) -> dict:
         "n_hosts": CHAOS_N_HOSTS,
         "slots_per_host": SLOTS_PER_HOST,
         "gossip_delay": GOSSIP_DELAY,
-        "decode_compiles": engine._decode._cache_size(),
+        "decode_compiles": engine.program._decode._cache_size(),
         "base": base,
         "kill_runs": kill_runs,
         "kill_sim": kill_sim,
@@ -470,7 +470,7 @@ def run_overload(seed: int = 0, failpoints: str | None = None) -> dict:
     # stage -> compile count (stages sharing one width share one jit; a
     # shared jit reports the same count for each of its stages)
     stage_compiles = {st: jit._cache_size()
-                      for st, jit in engine._stage_decodes.items()}
+                      for st, jit in engine.program._stage_decodes.items()}
 
     overload = {
         "failpoints": spec_str,

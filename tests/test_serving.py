@@ -309,6 +309,90 @@ def test_overload_sheds_and_degrades_without_recompiling(served):
         (st.as_row(), st.sheds, st.degrades)   # wall_s alone may differ
 
 
+def test_engine_under_a_one_device_mesh_serves_the_same_tokens(served):
+    """Under a ``dist`` the program places its pool by the slot-pool
+    specs and pins every step's layout; on a one-device mesh the slots
+    stay unsharded, the tokens are those served alone, and the decode
+    step compiles once."""
+    from repro.launch.mesh import make_local_mesh
+    from repro.launch.sharding import DistContext
+    cfg = served["cfg"]
+    engine = Engine(cfg, served["engine"].params, n_slots=N_SLOTS,
+                    max_len=MAX_LEN, topk=4,
+                    dist=DistContext(make_local_mesh()))
+    results, _ = engine.run(mixed_length_workload(cfg.vocab, 10, seed=0))
+    for rid, req in results.items():
+        assert req.tokens == served["solo_tokens"][rid], rid
+    assert engine.program._decode._cache_size() == 1
+
+
+def _two_live_states(served):
+    """Two pool states of the served program holding the same three
+    requests, one step into decoding."""
+    engine, cfg = served["engine"], served["cfg"]
+    prog = engine.program
+    rng = np.random.default_rng(5)
+    reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab, n)
+                    .astype(np.int32), max_gen=8)
+            for i, n in enumerate((5, 9, 7))]
+    for slot, req in enumerate(reqs):
+        req.slot = slot
+    payloads = engine.prefill_pool.prefill_all(reqs)
+    states = []
+    for _ in range(2):
+        state = prog.init_state(N_SLOTS)
+        for req, payload in zip(reqs, payloads):
+            prog.insert_caches(state, req, payload[0])
+            prog.start(state, req, payload[1])
+        prog.step(engine.params, state)
+        states.append(state)
+    return prog, engine.params, states
+
+
+def _host(state):
+    return (jax.tree.map(np.asarray, state.caches),
+            np.asarray(state.tokens), np.asarray(state.pos),
+            np.asarray(state.active), state.live.copy())
+
+
+@pytest.mark.parametrize("op", ["compact", "clear"])
+def test_program_remaps_and_clears_its_device_slot_state(served, op):
+    """The control plane's two slot-range operations on the device-
+    resident state.  ``compact`` permutes the caches and the slot
+    vectors, ``perm[new] = old``, as the host-side remap did, and the
+    next step's top-1 ids follow the permutation.  ``clear`` stops
+    exactly the cleared rows decoding (ids 0, position held) and zeros
+    their tokens and positions; the other rows decode as before."""
+    prog, params, (ref, got) = _two_live_states(served)
+    prog.drop(ref, 2)
+    prog.drop(got, 2)
+    caches, tokens, pos, active, live = _host(got)
+    if op == "compact":
+        perm = np.array([2, 0, 1], np.int32)
+        prog.compact(got, perm)
+        want_caches = jax.tree.map(lambda b: b[:, perm], caches)
+        want = (tokens[perm], pos[perm], active[perm], live[perm])
+    else:
+        perm = np.arange(N_SLOTS)
+        prog.clear(got, 1, 2)
+        want_caches = caches
+        keep = perm != 1
+        want = (tokens * keep[:, None], pos * keep, active & keep,
+                live & keep)
+    now = _host(got)
+    for a, b in zip(jax.tree.leaves(now[0]), jax.tree.leaves(want_caches)):
+        np.testing.assert_array_equal(a, b)
+    for a, b in zip(now[1:], want):
+        np.testing.assert_array_equal(a, b)
+    ids_ref = prog.step(params, ref)
+    ids = prog.step(params, got)
+    decoding = want[2]
+    np.testing.assert_array_equal(ids[decoding], ids_ref[perm][decoding])
+    np.testing.assert_array_equal(ids[~decoding], 0)
+    np.testing.assert_array_equal(np.asarray(got.pos),
+                                  want[1] + decoding)
+
+
 def test_loadgen_is_deterministic():
     spec = LoadSpec(n_requests=20, vocab=128, rate=0.7, seed=123)
     a, b = make_workload(spec), make_workload(spec)
